@@ -65,6 +65,7 @@ from . import (adaptive_serve, chaos, codesign_sweep, decode, distortion,
                obs_overhead, rd_bounds, serve_throughput, speculative,
                testbed_profiles, weight_stats)
 from .common import banner
+from repro.launch.compile_cache import enable_compile_cache
 
 SECTIONS = {
     "fig2": ("Fig. 2  weight statistics", weight_stats.run),
@@ -181,6 +182,7 @@ def main(argv=None):
                     help="skip appending to BENCH_history.jsonl")
     args = ap.parse_args(argv)
     picks = args.only.split(",") if args.only else list(SECTIONS)
+    enable_compile_cache()
 
     t0 = time.monotonic()
     failures = []

@@ -6,10 +6,10 @@
                  quantizer the decode engine traces in-graph)
   decode_attn.py — fused dequant-attend flash-decoding kernel over
                  int8-held KV codes (DESIGN.md §13)
-  ops.py       — jit'd wrappers (+ CPU interpret fallback, padding,
+  ops.py       — jit'd wrappers (dispatch rule, padding,
                  QuantizedLinear record)
   ref.py       — pure-jnp oracles the tests allclose against
-  pallas_env.py — the REPRO_PALLAS_INTERPRET resolver every kernel's
+  pallas_env.py — the interpret-mode rule (off-TPU only) every kernel's
                  ``interpret=None`` default routes through
 
 Batch contract (DESIGN.md §3, §7): activations may carry any number of

@@ -6,13 +6,15 @@ the traced decode step materialized a dequantized copy of the full
 ``[L, B, T, KV, dh]`` cache in HBM (``kv_dequantize`` then the einsum of
 ``layers.decode_attention``) — doubling the cache traffic the b_kv
 codesign exists to shrink.  This kernel reads the int8-held codes
-directly and dequantizes per-tile in VMEM (the ``qmm.py`` in-VMEM
-dequant pattern: ``codes.astype(f32) * scales`` broadcast, gather-free),
-so HBM sees only the quantized bytes.
+directly and dequantizes per-tile in VMEM — each position's scale
+multiplies its column of scores and of probabilities, so the scales stay
+on the lane axis — and HBM sees only the quantized bytes.
 
 Layout: one query vector per sequence (decode), GQA-folded.  Grid is
 ``(B * KV, T / bt)`` — one program per (row, kv-head) owning the
-``[G, dh]`` query group, kv tiles innermost.  The online-softmax
+``[G, dh]`` query group, kv tiles innermost; per-position scales ride as
+``[B * KV, 1, T]`` and each program's cache length is a scalar-prefetch
+operand in SMEM.  The online-softmax
 ``m/l/acc`` scratch persists across the tile axis and flushes at the
 last tile (``flash.py``'s accumulation pattern).  Cache positions at or
 beyond ``cache_len`` are masked; a *fully* masked tile is an exact
@@ -51,17 +53,22 @@ def _tile_update(q, k_codes, v_codes, k_scales, v_scales, t_start,
                  cache_len, m, l, acc, *, window: int, scale: float):
     """One kv tile of the online-softmax recurrence, dequant included.
 
-    q [G, dh] f32; k/v codes [bt, dh] (int8 or float); scales [bt] f32;
-    m/l [G, 1], acc [G, dh] f32 running state.  Returns the updated
-    (m, l, acc).  Shared by the Pallas kernel body (on VMEM refs) and
-    the jnp reference (on array slices): identical ops, identical bits.
+    q [G, dh] f32; k/v codes [bt, dh] (int8 or float); scales [1, bt] f32
+    (one per cache position, along lanes); ``cache_len`` a scalar; m/l
+    [G, 1], acc [G, dh] f32 running state.  Returns the updated
+    (m, l, acc).  Each position's scale multiplies its score column
+    (``q . (c s) = (q . c) s``) and its probability column
+    (``sum_t p_t s_t v_t``), so the scales never leave the lane axis.
+    Shared by the Pallas kernel body (on VMEM refs) and the jnp reference
+    (on array slices): identical ops, identical bits.
     """
     bt = k_codes.shape[0]
     g = q.shape[0]
-    k = k_codes.astype(jnp.float32) * k_scales[:, None]     # in-VMEM dequant
-    v = v_codes.astype(jnp.float32) * v_scales[:, None]
+    k = k_codes.astype(jnp.float32)
+    v = v_codes.astype(jnp.float32)
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * scale
+                            preferred_element_type=jnp.float32)
+    s = s * k_scales * scale                                # in-VMEM dequant
     kpos = t_start + jax.lax.broadcasted_iota(jnp.int32, (g, bt), 1)
     valid = kpos < cache_len
     if window > 0:
@@ -73,7 +80,7 @@ def _tile_update(q, k_codes, v_codes, k_scales, v_scales, t_start,
     corr = jnp.exp(m - m_new)
     l = l * corr + jnp.sum(p, axis=1, keepdims=True)
     acc = acc * corr + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())),
+        p * v_scales, v, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
     return m_new, l, acc
 
@@ -86,7 +93,7 @@ def _tile_update(q, k_codes, v_codes, k_scales, v_scales, t_start,
 _tile_update_jit = jax.jit(_tile_update, static_argnames=("window", "scale"))
 
 
-def _qdecode_kernel(q_ref, k_ref, v_ref, ks_ref, vs_ref, len_ref, o_ref,
+def _qdecode_kernel(len_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref, o_ref,
                     acc_ref, m_ref, l_ref, *, n_t: int, bt: int,
                     window: int, scale: float):
     j = pl.program_id(1)
@@ -99,8 +106,8 @@ def _qdecode_kernel(q_ref, k_ref, v_ref, ks_ref, vs_ref, len_ref, o_ref,
 
     m, l, acc = _tile_update(
         q_ref[0].astype(jnp.float32), k_ref[0], v_ref[0], ks_ref[0],
-        vs_ref[0], j * bt, len_ref[0, 0], m_ref[...], l_ref[...],
-        acc_ref[...], window=window, scale=scale)
+        vs_ref[0], j * bt, len_ref[pl.program_id(0)], m_ref[...],
+        l_ref[...], acc_ref[...], window=window, scale=scale)
     m_ref[...] = m
     l_ref[...] = l
     acc_ref[...] = acc
@@ -112,17 +119,21 @@ def _qdecode_kernel(q_ref, k_ref, v_ref, ks_ref, vs_ref, len_ref, o_ref,
 
 
 def _fold_heads(q, k_codes, v_codes, k_scales, v_scales, cache_len):
-    """[B, ...] layouts -> the kernel's GQA-folded [B*KV, ...] layouts."""
+    """[B, ...] layouts -> the kernel's GQA-folded [B*KV, ...] layouts.
+
+    Scales fold to [B*KV, 1, T] so a tile's block is (1, bt) in its last
+    two dims, the leading 1 equal to the array's; lengths fold to one
+    int32 per program, read from SMEM."""
     b, _, h, dh = q.shape
     t, kv = k_codes.shape[1], k_codes.shape[2]
     g = h // kv
     qr = q.reshape(b, kv, g, dh).reshape(b * kv, g, dh)
     kr = k_codes.transpose(0, 2, 1, 3).reshape(b * kv, t, dh)
     vr = v_codes.transpose(0, 2, 1, 3).reshape(b * kv, t, dh)
-    ksr = k_scales.transpose(0, 2, 1).reshape(b * kv, t)
-    vsr = v_scales.transpose(0, 2, 1).reshape(b * kv, t)
+    ksr = k_scales.transpose(0, 2, 1).reshape(b * kv, 1, t)
+    vsr = v_scales.transpose(0, 2, 1).reshape(b * kv, 1, t)
     lens = jnp.broadcast_to(jnp.reshape(cache_len, (-1, 1)), (b, kv))
-    lens = lens.astype(jnp.int32).reshape(b * kv, 1)
+    lens = lens.astype(jnp.int32).reshape(b * kv)
     return qr, kr, vr, ksr, vsr, lens
 
 
@@ -151,26 +162,31 @@ def quantized_decode_attention(q, k_codes, v_codes, k_scales, v_scales,
 
     kernel = functools.partial(_qdecode_kernel, n_t=n_t, bt=bt,
                                window=window, scale=dh ** -0.5)
+    # index maps take the prefetched lengths as a trailing argument
+    row = lambda bh, j, lens: (bh, 0, 0)
+    tile = lambda bh, j, lens: (bh, j, 0)
+    scales = lambda bh, j, lens: (bh, 0, j)
     out = pl.pallas_call(
         kernel,
-        grid=(b * kv, n_t),
-        in_specs=[
-            pl.BlockSpec((1, g, dh), lambda bh, j: (bh, 0, 0)),
-            pl.BlockSpec((1, bt, dh), lambda bh, j: (bh, j, 0)),
-            pl.BlockSpec((1, bt, dh), lambda bh, j: (bh, j, 0)),
-            pl.BlockSpec((1, bt), lambda bh, j: (bh, j)),
-            pl.BlockSpec((1, bt), lambda bh, j: (bh, j)),
-            pl.BlockSpec((1, 1), lambda bh, j: (bh, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, g, dh), lambda bh, j: (bh, 0, 0)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(b * kv, n_t),
+            in_specs=[
+                pl.BlockSpec((1, g, dh), row),
+                pl.BlockSpec((1, bt, dh), tile),
+                pl.BlockSpec((1, bt, dh), tile),
+                pl.BlockSpec((1, 1, bt), scales),
+                pl.BlockSpec((1, 1, bt), scales),
+            ],
+            out_specs=pl.BlockSpec((1, g, dh), row),
+            scratch_shapes=[
+                pltpu.VMEM((g, dh), jnp.float32),
+                pltpu.VMEM((g, 1), jnp.float32),
+                pltpu.VMEM((g, 1), jnp.float32),
+            ]),
         out_shape=jax.ShapeDtypeStruct((b * kv, g, dh), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((g, dh), jnp.float32),
-            pltpu.VMEM((g, 1), jnp.float32),
-            pltpu.VMEM((g, 1), jnp.float32),
-        ],
         interpret=interpret,
-    )(qr, kr, vr, ksr, vsr, lens)
+    )(lens, qr, kr, vr, ksr, vsr)
     return out.reshape(b, 1, h, dh)
 
 
@@ -203,7 +219,7 @@ def quantized_decode_attention_ref(q, k_codes, v_codes, k_scales, v_scales,
             sl = slice(j * bt, (j + 1) * bt)
             m, l, acc = _tile_update_jit(
                 qr[bh].astype(jnp.float32), kr[bh, sl], vr[bh, sl],
-                ksr[bh, sl], vsr[bh, sl], j * bt, lens[bh, 0], m, l, acc,
+                ksr[bh, :, sl], vsr[bh, :, sl], j * bt, lens[bh], m, l, acc,
                 window=window, scale=scale)
         rows.append((acc / jnp.maximum(l, 1e-30)).astype(q.dtype))
     return jnp.stack(rows).reshape(b, 1, h, dh)

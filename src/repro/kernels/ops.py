@@ -1,8 +1,8 @@
 """Jit'd public wrappers around the Pallas kernels.
 
-Handles: CPU fallback (interpret mode), shape padding to block multiples,
->2-D activations (leading dims are flattened into M), and a convenience
-``QuantizedLinear`` record the serving engine stores per weight matrix.
+Handles: shape padding to block multiples, >2-D activations (leading
+dims are flattened into M), and a convenience ``QuantizedLinear`` record
+the serving engine stores per weight matrix.
 
 The leading-dim flattening is the serving batch contract (DESIGN.md §7):
 ``[B, S, K]`` — B requests packed by the batched engine — and ``[S, K]``
@@ -16,12 +16,14 @@ jitted core, so the core's trace cache keys on the bucketed shape — any
 two row counts in one bucket share a single trace/compile instead of one
 per distinct M.  Zero-padding rows is invisible: each output row depends
 only on its own input row, and the pad is sliced off on the way out.
-The reference fallback (misaligned K/N — non-production weights) stays
-unpadded: there the padding would only buy wasted matmul rows.
 
-On TPU these dispatch the compiled Pallas kernels; on this CPU container the
-same kernel bodies run under ``interpret=True`` (numerics identical, speed
-irrelevant — tests assert allclose vs ref.py).
+Dispatch rule: a weight whose K and N are 128-aligned (every projection of
+the registry's configs, K=896 int4 included) runs the Pallas kernel.  On a
+TPU backend that kernel is compiled, and a shape it cannot tile raises
+``ValueError``: the jnp reference never stands in for the device.  Off-TPU
+the kernel bodies run under ``interpret=True`` (numerics identical, speed
+irrelevant — tests assert allclose vs ref.py), and a misaligned test shape
+falls back to the unpadded reference.
 """
 
 from __future__ import annotations
@@ -36,9 +38,23 @@ from . import qmm as _qmm
 from . import quantize as _quantize
 from . import ref as _ref
 from .bucketing import row_bucket
-from .pallas_env import use_interpret
+from .pallas_env import on_tpu, use_interpret
 
 
+def _kernel_tiles(k: int, n: int, n_groups: int) -> bool:
+    """Can the quantized-matmul kernels tile a [K, N] weight with
+    ``n_groups`` scale groups along K?"""
+    return k % 128 == 0 and n % 128 == 0 and k % n_groups == 0
+
+
+def _reference_off_tpu(what: str, shape) -> None:
+    """Allow the jnp reference for a shape the kernel cannot tile, off
+    TPU only; on a TPU backend raise instead."""
+    if on_tpu():
+        raise ValueError(
+            f"{what}: shape {tuple(shape)} has no Pallas tiling (K and N "
+            "must be multiples of 128, K of the group size); the TPU path "
+            "has no reference fallback")
 
 
 def _pick_block(dim: int, target: int, quantum: int) -> int:
@@ -63,11 +79,15 @@ def _bucket_rows(xm: jax.Array):
     return xm, m0
 
 
-# the off-fast-path reference matmuls, jitted per exact shape (no row
-# bucketing: padding would only waste reference-path compute, and
-# misaligned K/N means a non-production weight anyway)
+# the off-TPU reference matmuls for misaligned test shapes, jitted per
+# exact shape (no row bucketing: padding would only waste compute)
 _qmm_ref_jit = jax.jit(_ref.qmm_ref)
 _qmm_int4_ref_jit = jax.jit(_ref.qmm_int4_ref)
+_group_quantize_ref_jit = jax.jit(_ref.group_quantize_ref,
+                                  static_argnames=("group_size", "bits"))
+_group_quantize_jit = jax.jit(_quantize.group_quantize,
+                              static_argnames=("group_size", "bits",
+                                               "block_n", "interpret"))
 
 
 @functools.partial(jax.jit, static_argnames=("block_m", "block_n", "block_k",
@@ -94,9 +114,8 @@ def quantized_matmul(x: jax.Array, codes: jax.Array, scales: jax.Array,
     k = x.shape[-1]
     n = codes.shape[1]
     xm = x.reshape(-1, k)
-    # K/N must be block multiples for the production weights (all assigned
-    # configs are 128-aligned); fall back to the reference path when not.
-    if k % 128 != 0 or n % 128 != 0 or k % (k // scales.shape[0]) != 0:
+    if not _kernel_tiles(k, n, scales.shape[0]):
+        _reference_off_tpu("quantized_matmul", codes.shape)
         return _qmm_ref_jit(xm, codes, scales).reshape(*lead, n)
     xm, m0 = _bucket_rows(xm)
     # snap block_m to a 128-multiple divisor of the bucketed M so any
@@ -112,7 +131,10 @@ def quantized_matmul(x: jax.Array, codes: jax.Array, scales: jax.Array,
 def _qmm_int4_core(xm: jax.Array, packed: jax.Array, scales: jax.Array,
                    *, block_m: int, block_n: int, block_k: int,
                    interpret: bool) -> jax.Array:
-    """Pallas packed-int4 core on a row-bucketed, block-aligned [M, K]."""
+    """Pallas packed-int4 core on a row-bucketed, block-aligned [M, K].
+
+    K tiles in multiples of 256 (so a packed block keeps 128-row
+    multiples); a K that is not one (K=896) runs as a single full-K block."""
     k = xm.shape[-1]
     group = k // scales.shape[0]
     bk = _pick_block(k, block_k, max(group, 256))
@@ -132,7 +154,8 @@ def quantized_matmul_int4(x: jax.Array, packed: jax.Array,
     k = x.shape[-1]
     n = packed.shape[1]
     xm = x.reshape(-1, k)
-    if k % 256 != 0 or n % 128 != 0:
+    if not _kernel_tiles(k, n, scales.shape[0]):
+        _reference_off_tpu("quantized_matmul_int4", (k, n))
         return _qmm_int4_ref_jit(xm, packed, scales).reshape(*lead, n)
     xm, m0 = _bucket_rows(xm)
     out = _qmm_int4_core(xm, packed, scales,
@@ -142,13 +165,12 @@ def quantized_matmul_int4(x: jax.Array, packed: jax.Array,
     return out[:m0].reshape(*lead, n)
 
 
-@functools.partial(jax.jit, static_argnames=("group_size", "bits",
-                                             "interpret"))
 def group_quantize(w: jax.Array, *, group_size: int = 128, bits: int = 8,
                    interpret: bool | None = None):
-    """Fused quantizer; falls back to the jnp reference off the fast path.
+    """Fused quantizer; off TPU, falls back to the jnp reference for
+    shapes the kernel cannot tile (on TPU those raise).
 
-    Fast path: K tiles into ``group_size`` groups and N is 128-aligned —
+    Kernel path: K tiles into ``group_size`` groups and N is 128-aligned —
     the fused Pallas quantizer.  Off it, the reference quantizer runs with
     the largest group layout the shape admits:
 
@@ -160,13 +182,14 @@ def group_quantize(w: jax.Array, *, group_size: int = 128, bits: int = 8,
     interpret = use_interpret() if interpret is None else interpret
     k, n = w.shape
     if k % group_size == 0 and n % 128 == 0:
-        return _quantize.group_quantize(w, group_size=group_size, bits=bits,
-                                        block_n=_pick_block(n, 512, 128),
-                                        interpret=interpret)
+        return _group_quantize_jit(w, group_size=group_size, bits=bits,
+                                   block_n=_pick_block(n, 512, 128),
+                                   interpret=interpret)
+    _reference_off_tpu("group_quantize", w.shape)
     g = min(group_size, k)
     if k % g == 0:
-        return _ref.group_quantize_ref(w, group_size=g, bits=bits)
-    return _ref.group_quantize_ref(w, 1, bits=bits)
+        return _group_quantize_ref_jit(w, group_size=g, bits=bits)
+    return _group_quantize_ref_jit(w, group_size=1, bits=bits)
 
 
 # ---------------------------------------------------------------------------

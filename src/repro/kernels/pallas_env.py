@@ -1,50 +1,28 @@
-"""Runtime flag for Pallas interpret mode (DESIGN.md §13).
+"""Runtime rule for Pallas interpret mode (DESIGN.md §13).
 
 Every Pallas kernel in ``kernels/`` needs the same decision at dispatch
 time: run the compiled TPU kernel, or execute the identical kernel body
 under ``interpret=True`` (pure-jax evaluation — numerics identical,
-speed irrelevant) because no TPU is attached.  Before PR 7 each wrapper
-re-derived it from ``jax.default_backend()``; :func:`use_interpret`
-centralizes the rule and adds an environment override so CI, containers,
-and debugging sessions can force either mode without touching call
-sites:
-
-    REPRO_PALLAS_INTERPRET=1      always interpret (CI sets this)
-    REPRO_PALLAS_INTERPRET=0      always compile (TPU required)
-    REPRO_PALLAS_INTERPRET=auto   interpret iff the backend is not TPU
-                                  (the default when unset)
+speed irrelevant) because no TPU is attached.  Interpret mode exists for
+the CPU test suite only: on a TPU backend the kernels always compile, and
+nothing (no flag, no environment variable) turns them back into the
+interpreter.
 
 Kernel wrappers keep an explicit ``interpret=`` parameter; ``None``
-defers to this resolver.
+defers to :func:`use_interpret`.
 """
 
 from __future__ import annotations
 
-import os
-
 import jax
 
-ENV_VAR = "REPRO_PALLAS_INTERPRET"
 
-_TRUE = ("1", "true", "yes", "on")
-_FALSE = ("0", "false", "no", "off")
+def on_tpu() -> bool:
+    """Is the active jax backend a TPU?"""
+    return jax.default_backend() == "tpu"
 
 
 def use_interpret() -> bool:
-    """Should Pallas kernels run in interpret mode right now?
-
-    Resolution order: the ``REPRO_PALLAS_INTERPRET`` environment
-    variable when set to an explicit boolean, otherwise (``auto`` /
-    unset) interpret exactly when the active jax backend is not a TPU.
-    Raises ``ValueError`` on an unrecognized value — a silently ignored
-    typo here would send CI onto a nonexistent TPU path.
-    """
-    val = os.environ.get(ENV_VAR, "auto").strip().lower()
-    if val in _TRUE:
-        return True
-    if val in _FALSE:
-        return False
-    if val not in ("", "auto"):
-        raise ValueError(
-            f"{ENV_VAR}={val!r}: expected one of 1/0/true/false/auto")
-    return jax.default_backend() != "tpu"
+    """Should Pallas kernels run in interpret mode right now?  Exactly
+    when the backend is not a TPU."""
+    return not on_tpu()

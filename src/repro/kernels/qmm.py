@@ -11,7 +11,7 @@ Tiling (all MXU-aligned, multiples of 128 on M/N/K):
   grid = (M/bm, N/bn, K/bk)    K innermost -> sequential accumulation
   x tile      [bm, bk]  VMEM   (f32/bf16 activations)
   codes tile  [bk, bn]  VMEM   int8   (or [bk/2, bn] packed int4)
-  scales tile [bk/G, bn] VMEM  f32    per-(group, out-channel), G | bk
+  scales tile [bk/G, 1, bn] VMEM f32   per-(group, out-channel), G | bk
   acc scratch [bm, bn]  VMEM   f32    (zeroed at k==0, flushed at k==K-1)
 
 VMEM working set at defaults (bm=bn=256, bk=512, G=128):
@@ -19,8 +19,13 @@ VMEM working set at defaults (bm=bn=256, bk=512, G=128):
   acc 256*256*4 = 256 KiB  ->  ~0.9 MiB of ~16 MiB VMEM.  Double-buffered
   inputs stay well under budget.
 
-The kernel body is dtype-polymorphic; on this CPU container it is validated
-with ``interpret=True`` against ``ref.qmm_ref`` (see tests/test_kernels.py).
+The scales ride as a [K/G, 1, N] view of the [K/G, N] array, so their
+block's last two dims are (1, bn): the TPU lowering takes a block dim that
+equals the array's, and a [bk/G, bn] block with bk/G < 8 rows (every K of
+a 128-group model below 1024) is refused.
+
+The kernel body is dtype-polymorphic; off-TPU tests validate it with
+``interpret=True`` against ``ref.qmm_ref`` (see tests/test_kernels.py).
 """
 
 from __future__ import annotations
@@ -33,6 +38,23 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .pallas_env import use_interpret
+
+
+def _dequant(codes, scales, group_size: int):
+    """codes [bk, bn] * scales [bk//G, 1, bn] -> f32 [bk, bn].
+
+    A grouped reshape + broadcast multiply: a layout-only expansion the
+    compiler folds into the multiply, where jnp.repeat lowers to a VMEM
+    gather."""
+    bk, bn = codes.shape
+    return (codes.astype(jnp.float32).reshape(bk // group_size, group_size, bn)
+            * scales).reshape(bk, bn)
+
+
+def _scales_spec(block_k: int, block_n: int, group_size: int):
+    """BlockSpec of the [K//G, 1, N] scales view for grid (i, j, kk)."""
+    return pl.BlockSpec((block_k // group_size, 1, block_n),
+                        lambda i, j, kk: (kk, 0, j))
 
 
 # ---------------------------------------------------------------------------
@@ -48,14 +70,7 @@ def _qmm_kernel(x_ref, w_ref, s_ref, o_ref, acc_ref, *, n_k: int,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     codes = w_ref[...]                                    # [bk, bn] int8
-    scales = s_ref[...]                                   # [bk//G, bn] f32
-    bk, bn = codes.shape
-    # dequantize: expand scales along the group axis inside VMEM via a
-    # grouped reshape + broadcast multiply — a layout-only expansion the
-    # compiler folds into the multiply, where jnp.repeat lowers to a
-    # VMEM gather
-    w = (codes.astype(jnp.float32).reshape(bk // group_size, group_size, bn)
-         * scales[:, None, :]).reshape(bk, bn)
+    w = _dequant(codes, s_ref[...], group_size)
     acc_ref[...] += jnp.dot(x_ref[...].astype(jnp.float32), w,
                             preferred_element_type=jnp.float32)
 
@@ -94,14 +109,13 @@ def qmm(x: jax.Array, codes: jax.Array, scales: jax.Array, *,
         in_specs=[
             pl.BlockSpec((block_m, block_k), lambda i, j, kk: (i, kk)),
             pl.BlockSpec((block_k, block_n), lambda i, j, kk: (kk, j)),
-            pl.BlockSpec((block_k // group_size, block_n),
-                         lambda i, j, kk: (kk, j)),
+            _scales_spec(block_k, block_n, group_size),
         ],
         out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), x.dtype),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
         interpret=interpret,
-    )(x, codes, scales)
+    )(x, codes, scales.reshape(n_groups, 1, n))
 
 
 # ---------------------------------------------------------------------------
@@ -125,10 +139,7 @@ def _qmm_int4_kernel(x_ref, w_ref, s_ref, o_ref, acc_ref, *, n_k: int,
     bk2, bn = packed.shape
     bk = 2 * bk2
     codes = jnp.stack([lo, hi], axis=1).reshape(bk, bn)       # [bk, bn]
-    scales = s_ref[...]
-    # same gather-free scale expansion as the int8 body above
-    w = (codes.astype(jnp.float32).reshape(bk // group_size, group_size, bn)
-         * scales[:, None, :]).reshape(bk, bn)
+    w = _dequant(codes, s_ref[...], group_size)
     acc_ref[...] += jnp.dot(x_ref[...].astype(jnp.float32), w,
                             preferred_element_type=jnp.float32)
 
@@ -162,11 +173,10 @@ def qmm_int4(x: jax.Array, packed: jax.Array, scales: jax.Array, *,
         in_specs=[
             pl.BlockSpec((block_m, block_k), lambda i, j, kk: (i, kk)),
             pl.BlockSpec((block_k // 2, block_n), lambda i, j, kk: (kk, j)),
-            pl.BlockSpec((block_k // group_size, block_n),
-                         lambda i, j, kk: (kk, j)),
+            _scales_spec(block_k, block_n, group_size),
         ],
         out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), x.dtype),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
         interpret=interpret,
-    )(x, packed, scales)
+    )(x, packed, scales.reshape(n_groups, 1, n))

@@ -9,7 +9,10 @@ precision immediately.
 Tiling: grid = (K/G, N/bn); each step owns one [G, bn] group tile in VMEM,
 reduces absmax over the group axis, writes [G, bn] int8 codes and [1, bn]
 f32 scales.  G is the quantization group size (default 128 — one MXU lane
-tile), bn defaults to 512 -> ~320 KiB VMEM per step.
+tile), bn defaults to 512 -> ~320 KiB VMEM per step.  The scales are
+written through a [K/G, 1, N] view so each block's last two dims are
+(1, bn), a shape the TPU lowering accepts (a (1, bn) block of the
+[K/G, N] array is not).
 """
 
 from __future__ import annotations
@@ -29,7 +32,7 @@ def _group_quant_kernel(w_ref, codes_ref, scale_ref, *, levels: int):
     scale = jnp.where(amax > 0, amax / levels, 1.0)
     q = jnp.clip(jnp.round(w / scale), -levels, levels)
     codes_ref[...] = q.astype(jnp.int8)
-    scale_ref[...] = scale
+    scale_ref[0] = scale
 
 
 def group_quantize(w: jax.Array, *, group_size: int = 128, bits: int = 8,
@@ -48,21 +51,22 @@ def group_quantize(w: jax.Array, *, group_size: int = 128, bits: int = 8,
     levels = 2 ** (bits - 1) - 1
 
     kernel = functools.partial(_group_quant_kernel, levels=levels)
-    return pl.pallas_call(
+    codes, scales = pl.pallas_call(
         kernel,
         grid=(k // group_size, n // block_n),
         in_specs=[pl.BlockSpec((group_size, block_n),
                                lambda g, j: (g, j))],
         out_specs=[
             pl.BlockSpec((group_size, block_n), lambda g, j: (g, j)),
-            pl.BlockSpec((1, block_n), lambda g, j: (g, j)),
+            pl.BlockSpec((1, 1, block_n), lambda g, j: (g, 0, j)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((k, n), jnp.int8),
-            jax.ShapeDtypeStruct((k // group_size, n), jnp.float32),
+            jax.ShapeDtypeStruct((k // group_size, 1, n), jnp.float32),
         ],
         interpret=interpret,
     )(w)
+    return codes, scales.reshape(k // group_size, n)
 
 
 # ---------------------------------------------------------------------------

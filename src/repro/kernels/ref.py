@@ -11,10 +11,14 @@ Shapes / conventions shared with qmm.py and quantize.py:
 ``group_quantize_ref`` is the oracle for the fused quantizer kernel:
 symmetric absmax scaling per (group, column), matching
 ``repro.core.quantization`` with scheme='uniform', granularity='per-group'.
+``decode_attention_ref`` is the oracle for the quantized decode attention
+(``decode_attn.py``): it dequantizes the whole cache and attends at once,
+sharing nothing with the kernel's tiled schedule.
 """
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 
@@ -80,3 +84,30 @@ def qmm_int4_ref(x: jnp.ndarray, packed: jnp.ndarray,
     """Oracle for the int4-packed matmul (unpack along K, then qmm)."""
     codes = unpack_int4_ref(packed)
     return qmm_ref(x, codes, scales)
+
+
+def decode_attention_ref(q, k_codes, v_codes, k_scales, v_scales,
+                         cache_len, *, window: int = 0):
+    """Oracle for the quantized decode attention: dequantize the whole
+    cache, then one masked softmax per query head.
+
+    q [B, 1, H, dh]; codes [B, T, KV, dh]; scales [B, T, KV]; cache_len
+    [B] (positions at or beyond it are masked, and with ``window`` > 0
+    so are those more than ``window`` back).  Returns [B, 1, H, dh] f32.
+    """
+    b, _, h, dh = q.shape
+    t, kv = k_codes.shape[1], k_codes.shape[2]
+    k = k_codes.astype(jnp.float32) * k_scales[..., None]
+    v = v_codes.astype(jnp.float32) * v_scales[..., None]
+    # GQA: query head i reads KV head i // (H / KV)
+    k = jnp.repeat(k, h // kv, axis=2)                   # [B, T, H, dh]
+    v = jnp.repeat(v, h // kv, axis=2)
+    s = jnp.einsum("bhd,bthd->bht", q[:, 0].astype(jnp.float32), k) \
+        * dh ** -0.5
+    pos = jnp.arange(t)[None, None, :]
+    n = jnp.reshape(cache_len, (-1, 1, 1))
+    valid = pos < n
+    if window > 0:
+        valid &= pos >= n - window
+    p = jax.nn.softmax(jnp.where(valid, s, -jnp.inf), axis=-1)
+    return jnp.einsum("bht,bthd->bhd", p, v)[:, None]

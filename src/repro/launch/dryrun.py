@@ -43,7 +43,6 @@ from ..configs import ALL_SHAPES, ARCH_IDS, cell_applicable, get_config
 from ..configs.base import ModelConfig, ShapeSpec
 from ..core.quantization import QuantConfig, quantize_tree_stacked
 from ..models.registry import build_model
-from .mesh import set_mesh
 from ..optim import AdamW, AdamWState
 from ..parallel.sharding import (activation_sharding, batch_shardings,
                                  default_rules, replicated, tree_shardings)
@@ -209,7 +208,7 @@ def run_cell(arch: str, shape: ShapeSpec, *, multi_pod: bool,
         from ..parallel.sharding import flash_attention_mode
         flash_ctx = flash_attention_mode(
             mesh if "flash" in variant else None)
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             with activation_sharding(seq_spec), flash_ctx:
                 jitted = jax.jit(fn, in_shardings=shardings,
                                  donate_argnums=donate)

@@ -9,40 +9,13 @@ data parallelism (gradient all-reduce, optionally int8-compressed).
 from __future__ import annotations
 
 import jax
-
-
-def compat_make_mesh(shape, axes, devices=None):
-    """``jax.make_mesh`` across jax versions: ``axis_types`` (and
-    ``jax.sharding.AxisType``) only exist in newer releases; older ones
-    default to Auto axes anyway, so omit the kwarg when absent."""
-    kwargs = {} if devices is None else {"devices": devices}
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        kwargs["axis_types"] = (axis_type.Auto,) * len(axes)
-    return jax.make_mesh(shape, axes, **kwargs)
-
-
-def make_abstract_mesh(shape, axes):
-    """``jax.sharding.AbstractMesh`` across jax versions: new releases take
-    ``(shape, axis_names)``, old ones a tuple of ``(name, size)`` pairs."""
-    try:
-        return jax.sharding.AbstractMesh(tuple(shape), tuple(axes))
-    except TypeError:
-        return jax.sharding.AbstractMesh(tuple(zip(axes, shape)))
-
-
-def set_mesh(mesh):
-    """Context manager making ``mesh`` ambient: ``jax.set_mesh`` on new jax,
-    the classic ``with mesh:`` (Mesh.__enter__) on versions without it."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat_make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(data: int = 1, model: int = 1):
@@ -50,7 +23,8 @@ def make_host_mesh(data: int = 1, model: int = 1):
     n = len(jax.devices())
     data = min(data, n)
     model = min(model, max(n // data, 1))
-    return compat_make_mesh((data, model), ("data", "model"))
+    return jax.make_mesh((data, model), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 # TPU v5e hardware constants (roofline targets; assignment §ROOFLINE)

@@ -64,6 +64,7 @@ from ..runtime import (AdaptiveCoInferenceEngine, BatchedCoInferenceEngine,
                        ServingSupervisor, SpeculativeDecodeEngine,
                        greedy_decode_reference)
 from ..runtime.decode_engine import decode_protocol_gap
+from .compile_cache import enable_compile_cache
 
 # the realizable draft-container rungs --speculative may pin
 SPEC_DRAFT_CHOICES = (2, 4, 8)
@@ -79,6 +80,13 @@ ENV_TRACES = {
 
 
 def main(argv=None):
+    """Command-line entry: turn on the persistent compilation cache, then
+    serve (tests call :func:`run`, which leaves the cache alone)."""
+    enable_compile_cache()
+    return run(argv)
+
+
+def run(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen2-0.5b")
     ap.add_argument("--smoke", action="store_true")

@@ -29,7 +29,6 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..checkpoint import CheckpointManager
-from ..launch.mesh import set_mesh
 from ..core.quantization import QuantConfig
 from ..optim import AdamW, AdamWState, compress_tree, init_error_state
 from ..parallel.sharding import (batch_shardings, default_rules, replicated,
@@ -149,7 +148,7 @@ class Trainer:
     # state init / restore
     # ------------------------------------------------------------------
     def init_state(self, rng):
-        with set_mesh(self.mesh):
+        with jax.set_mesh(self.mesh):
             params = jax.jit(
                 self.model.init,
                 out_shardings=self.param_shardings())(rng)
@@ -197,7 +196,7 @@ class Trainer:
 
         history = []
         t_last = time.monotonic()
-        with set_mesh(self.mesh):
+        with jax.set_mesh(self.mesh):
             for step in range(start, start + num_steps):
                 batch = next(loader)
                 params, opt_state, err, metrics = self._step_fn(

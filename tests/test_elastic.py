@@ -24,7 +24,6 @@ from repro.checkpoint import CheckpointManager
 from repro.configs import get_smoke
 from repro.data import MarkovLMConfig, MarkovLMDataset, ShardedLoader
 from repro.models.registry import build_model
-from repro.launch.mesh import compat_make_mesh
 from repro.optim import AdamW
 from repro.parallel.sharding import default_rules
 from repro.runtime import TrainConfig, Trainer
@@ -34,8 +33,9 @@ assert len(jax.devices()) == 8, jax.devices()
 
 def make_mesh(n):
     # (data, model) over n devices, TP degree 2
-    return compat_make_mesh((n // 2, 2), ("data", "model"),
-                            devices=jax.devices()[:n])
+    return jax.make_mesh((n // 2, 2), ("data", "model"),
+                         devices=jax.devices()[:n],
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
 
 
 def session(ckpt_dir, n_devices, steps):

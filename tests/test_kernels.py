@@ -216,3 +216,49 @@ def test_prop_qmm_random_shapes(mm, kk, nn, seed):
     np.testing.assert_allclose(
         np.asarray(ops.quantized_matmul(x, codes, scales)),
         np.asarray(ref.qmm_ref(x, codes, scales)), rtol=1e-4, atol=1e-4)
+
+
+def test_int4_k896_dispatches_to_kernel(monkeypatch):
+    """qwen2-0.5b's K=896 projections (896 = 7 x 128, not a multiple of
+    256) run the int4 Pallas kernel as one full-K block — never the jnp
+    reference, which would hide the device on a TPU."""
+    def no_reference(*a, **kw):
+        raise AssertionError("K=896 int4 fell back to the jnp reference")
+
+    monkeypatch.setattr(ops, "_qmm_int4_ref_jit", no_reference)
+    kx, kw = jax.random.split(jax.random.PRNGKey(896))
+    x = jax.random.normal(kx, (2, 3, 896))
+    w = jax.random.normal(kw, (896, 128))
+    ql = ops.quantize_linear(w, bits=4)
+    out = ql.apply(x)
+    want = ref.qmm_int4_ref(x.reshape(-1, 896), ql.codes, ql.scales)
+    np.testing.assert_allclose(np.asarray(out).reshape(-1, 128),
+                               np.asarray(want), rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("call", ["int8", "int4", "quantize"])
+def test_tpu_backend_raises_instead_of_reference(monkeypatch, call):
+    """On a TPU backend a shape the kernels cannot tile raises; the jnp
+    reference is an off-TPU test fallback only."""
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)
+    w = jax.random.normal(jax.random.PRNGKey(0), (256, 100))
+    x = jnp.ones((4, 256))
+    with pytest.raises(ValueError, match="no Pallas tiling"):
+        if call == "int8":
+            codes, scales = ref.group_quantize_ref(w, 128)
+            ops.quantized_matmul(x, codes, scales)
+        elif call == "int4":
+            codes, scales = ref.group_quantize_ref(w, 128, bits=4)
+            ops.quantized_matmul_int4(x, ref.pack_int4_ref(codes), scales)
+        else:
+            ops.group_quantize(w, group_size=128)
+
+
+def test_use_interpret_follows_backend(monkeypatch):
+    """Interpret mode is exactly 'no TPU attached': on a TPU backend the
+    kernels always compile."""
+    from repro.kernels import pallas_env
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert pallas_env.use_interpret() is False
+    monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
+    assert pallas_env.use_interpret() is True

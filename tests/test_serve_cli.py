@@ -14,7 +14,7 @@ from repro.launch import serve
 def test_compiled_with_unsupported_arch_errors_cleanly(capsys):
     # xlstm-350m is outside the dense DecoderLM family: --compiled has
     # no embed/run_layers_window hooks to trace (DESIGN.md §10)
-    rc = serve.main(["--arch", "xlstm-350m", "--smoke", "--compiled"])
+    rc = serve.run(["--arch", "xlstm-350m", "--smoke", "--compiled"])
     err = capsys.readouterr().err
     assert rc == 2
     assert err.startswith("error:")
@@ -25,7 +25,7 @@ def test_compiled_with_unsupported_arch_errors_cleanly(capsys):
 def test_unsupported_arch_errors_cleanly_without_compiled(capsys):
     # ... and the generic co-inference protocol mismatch is also a
     # clean error, not a constructor traceback
-    rc = serve.main(["--arch", "xlstm-350m", "--smoke"])
+    rc = serve.run(["--arch", "xlstm-350m", "--smoke"])
     err = capsys.readouterr().err
     assert rc == 2
     assert err.startswith("error:")
@@ -65,7 +65,7 @@ def test_decode_with_unsupported_arch_errors_cleanly(arch, capsys):
     # encdec/hybrid archs define prefill/decode_step but not the dense
     # [layers, batch, cache_seq, kv_heads, head_dim] KV cache the decode
     # engine batches over (DESIGN.md §12) -> clean exit 2, no traceback
-    rc = serve.main(["--arch", arch, "--smoke", "--decode"])
+    rc = serve.run(["--arch", arch, "--smoke", "--decode"])
     err = capsys.readouterr().err
     assert rc == 2
     assert err.startswith("error:")
@@ -77,7 +77,7 @@ def test_decode_with_fcdnn_errors_cleanly(capsys):
     # fcdnn-16 ships no ModelConfig at all (it is the distortion-
     # benchmark toy); any serve invocation must fail one-line, not with
     # a build_model traceback
-    rc = serve.main(["--arch", "fcdnn-16", "--smoke", "--decode"])
+    rc = serve.run(["--arch", "fcdnn-16", "--smoke", "--decode"])
     err = capsys.readouterr().err
     assert rc == 2
     assert err.startswith("error:")
@@ -90,7 +90,7 @@ def test_decode_with_fcdnn_errors_cleanly(capsys):
 def test_fleet_spec_validation_errors_cleanly(tmp_path, payload, capsys):
     spec = tmp_path / "fleet.json"
     spec.write_text(payload)
-    rc = serve.main(["--smoke", "--fleet", str(spec)])
+    rc = serve.run(["--smoke", "--fleet", str(spec)])
     err = capsys.readouterr().err
     assert rc == 2
     assert err.startswith("error:")
@@ -98,7 +98,7 @@ def test_fleet_spec_validation_errors_cleanly(tmp_path, payload, capsys):
 
 
 def test_fleet_spec_missing_file_errors_cleanly(tmp_path, capsys):
-    rc = serve.main(["--smoke", "--fleet", str(tmp_path / "nope.json")])
+    rc = serve.run(["--smoke", "--fleet", str(tmp_path / "nope.json")])
     err = capsys.readouterr().err
     assert rc == 2
     assert err.startswith("error:")
@@ -119,7 +119,7 @@ def test_fleet_spec_bad_agent_entries_error_cleanly(tmp_path, agent,
                                                     capsys):
     spec = tmp_path / "fleet.json"
     spec.write_text(json.dumps({"agents": [agent]}))
-    rc = serve.main(["--smoke", "--fleet", str(spec)])
+    rc = serve.run(["--smoke", "--fleet", str(spec)])
     err = capsys.readouterr().err
     assert rc == 2
     assert err.startswith("error: fleet agent")
@@ -136,7 +136,7 @@ def test_fleet_spec_bad_agent_entries_error_cleanly(tmp_path, agent,
 def test_chaos_spec_validation_errors_cleanly(tmp_path, payload, capsys):
     spec = tmp_path / "chaos.json"
     spec.write_text(payload)
-    rc = serve.main(["--smoke", "--chaos-trace", str(spec)])
+    rc = serve.run(["--smoke", "--chaos-trace", str(spec)])
     err = capsys.readouterr().err
     assert rc == 2
     assert err.startswith("error: cannot load chaos trace")
@@ -144,7 +144,7 @@ def test_chaos_spec_validation_errors_cleanly(tmp_path, payload, capsys):
 
 
 def test_chaos_spec_missing_file_errors_cleanly(tmp_path, capsys):
-    rc = serve.main(["--smoke", "--chaos-trace",
+    rc = serve.run(["--smoke", "--chaos-trace",
                      str(tmp_path / "nope.json")])
     err = capsys.readouterr().err
     assert rc == 2
@@ -155,7 +155,7 @@ def test_chaos_with_sequential_engine_errors_cleanly(tmp_path, capsys):
     # the sequential engine has no queue/virtual clock to supervise
     spec = tmp_path / "chaos.json"
     spec.write_text('{"corruption": {"rate": 0.1}}')
-    rc = serve.main(["--smoke", "--engine", "sequential",
+    rc = serve.run(["--smoke", "--engine", "sequential",
                      "--chaos-trace", str(spec)])
     err = capsys.readouterr().err
     assert rc == 2
@@ -167,7 +167,7 @@ def test_chaos_smoke_run_prints_resilience_line(capsys):
     # the shipped example spec must keep driving a supervised smoke run
     example = pathlib.Path(__file__).resolve().parent.parent \
         / "examples" / "chaos_spec.json"
-    rc = serve.main(["--smoke", "--chaos-trace", str(example)])
+    rc = serve.run(["--smoke", "--chaos-trace", str(example)])
     out = capsys.readouterr().out
     assert rc == 0
     assert "resilience [supervised]:" in out
@@ -179,7 +179,7 @@ def test_speculative_with_unsupported_arch_errors_cleanly(arch, capsys):
     # --speculative rides the decode engine's dense KV-cache protocol;
     # non-decoder archs must die with the flag's own one-liner, not a
     # SpeculativeDecodeEngine constructor traceback (DESIGN.md §16)
-    rc = serve.main(["--arch", arch, "--smoke", "--speculative"])
+    rc = serve.run(["--arch", arch, "--smoke", "--speculative"])
     err = capsys.readouterr().err
     assert rc == 2
     assert err.startswith("error:")
@@ -188,7 +188,7 @@ def test_speculative_with_unsupported_arch_errors_cleanly(arch, capsys):
 
 
 def test_speculative_bad_lookahead_errors_cleanly(capsys):
-    rc = serve.main(["--smoke", "--speculative", "--lookahead", "0"])
+    rc = serve.run(["--smoke", "--speculative", "--lookahead", "0"])
     err = capsys.readouterr().err
     assert rc == 2
     assert err.startswith("error:")
@@ -199,7 +199,7 @@ def test_speculative_off_ladder_draft_bits_errors_cleanly(capsys):
     # b_draft must sit on the realizable container ladder: the draft
     # weights live in the same packed int4/int8 containers as every
     # other plan, so 3-bit drafts have nowhere to live
-    rc = serve.main(["--smoke", "--speculative", "--draft-bits", "3"])
+    rc = serve.run(["--smoke", "--speculative", "--draft-bits", "3"])
     err = capsys.readouterr().err
     assert rc == 2
     assert err.startswith("error:")
@@ -214,7 +214,7 @@ def test_fleet_spec_compiled_unsupported_arch_errors_cleanly(tmp_path,
         "agents": [{"name": "a", "arch": "xlstm-350m",
                     "t0": 1.0, "e0": 1.0}],
     }))
-    rc = serve.main(["--smoke", "--fleet", str(spec)])
+    rc = serve.run(["--smoke", "--fleet", str(spec)])
     err = capsys.readouterr().err
     assert rc == 2
     assert "compiled" in err and "xlstm-350m" in err

@@ -12,8 +12,7 @@ from repro.configs import get_smoke
 from repro.data import (CaptionProxyConfig, CaptionProxyDataset,
                         MarkovLMConfig, MarkovLMDataset, ShardedLoader)
 from repro.launch import hloparse
-from repro.launch.mesh import (make_abstract_mesh, make_host_mesh,
-                              set_mesh)
+from repro.launch.mesh import make_host_mesh
 from repro.models.registry import build_model
 from repro.parallel.sharding import (batch_shardings, default_rules,
                                      spec_for, tree_shardings)
@@ -25,7 +24,7 @@ from repro.parallel.sharding import (batch_shardings, default_rules,
 
 def test_spec_divisibility_fallback():
     # abstract 16x16 production mesh: no devices needed for spec logic
-    mesh = make_abstract_mesh((16, 16), ("data", "model"))
+    mesh = jax.sharding.AbstractMesh((16, 16), ("data", "model"))
     rules = {"heads": "model", "embed": "data", "kv": "model"}
     # divisible dims shard
     assert spec_for(("embed", "heads"), (64, 64), rules, mesh) == \
@@ -60,7 +59,7 @@ def test_jit_with_shardings_runs():
     b_sh = batch_shardings(
         {k: jax.ShapeDtypeStruct(v.shape, v.dtype)
          for k, v in batch.items()}, rules, mesh)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         params = jax.jit(model.init, out_shardings=p_sh)(
             jax.random.PRNGKey(0))
         loss = jax.jit(model.loss, in_shardings=(p_sh, b_sh))(params, batch)
