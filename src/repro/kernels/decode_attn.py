@@ -168,6 +168,7 @@ def quantized_decode_attention(q, k_codes, v_codes, k_scales, v_scales,
     scales = lambda bh, j, lens: (bh, 0, j)
     out = pl.pallas_call(
         kernel,
+        name="decode_attn",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(b * kv, n_t),

@@ -118,6 +118,7 @@ def flash_attention_fwd(q: jax.Array, k: jax.Array, v: jax.Array, *,
 
     out = pl.pallas_call(
         kernel,
+        name="flash_attn",
         grid=(b * h, n_q, n_k),
         in_specs=[
             pl.BlockSpec((1, bq, dh), lambda bh, i, j: (bh, i, 0)),

@@ -105,6 +105,7 @@ def qmm(x: jax.Array, codes: jax.Array, scales: jax.Array, *,
     kernel = functools.partial(_qmm_kernel, n_k=n_k, group_size=group_size)
     return pl.pallas_call(
         kernel,
+        name="qmm_int8",
         grid=(m // block_m, n // block_n, n_k),
         in_specs=[
             pl.BlockSpec((block_m, block_k), lambda i, j, kk: (i, kk)),
@@ -169,6 +170,7 @@ def qmm_int4(x: jax.Array, packed: jax.Array, scales: jax.Array, *,
                                group_size=group_size)
     return pl.pallas_call(
         kernel,
+        name="qmm_int4",
         grid=(m // block_m, n // block_n, n_k),
         in_specs=[
             pl.BlockSpec((block_m, block_k), lambda i, j, kk: (i, kk)),

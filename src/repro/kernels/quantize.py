@@ -53,6 +53,7 @@ def group_quantize(w: jax.Array, *, group_size: int = 128, bits: int = 8,
     kernel = functools.partial(_group_quant_kernel, levels=levels)
     codes, scales = pl.pallas_call(
         kernel,
+        name="group_quantize",
         grid=(k // group_size, n // block_n),
         in_specs=[pl.BlockSpec((group_size, block_n),
                                lambda g, j: (g, j))],

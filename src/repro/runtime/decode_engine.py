@@ -152,6 +152,7 @@ class DecodeRequest:
     qos: str
     max_new_tokens: int
     arrival_s: float            # virtual arrival time
+    submitted_wall_s: float = 0.0   # host clock at submit(), metrics on
 
 
 @dataclasses.dataclass(frozen=True)
@@ -273,7 +274,8 @@ def _build_prefill(model, b_kv: int) -> Callable:
     """
     raw = b_kv >= 16
 
-    def fn(weights, tokens, last_idx, slot, kc, vc, ks, vs, pos, tok):
+    def decode_prefill(weights, tokens, last_idx, slot, kc, vc, ks, vs,
+                       pos, tok):
         logits, cache = model.prefill(weights, {"tokens": tokens},
                                       last_index=last_idx)
         tok0 = jnp.argmax(logits, axis=-1).astype(jnp.int32)
@@ -295,7 +297,7 @@ def _build_prefill(model, b_kv: int) -> Callable:
         tok = jax.lax.dynamic_update_slice(tok, tok0, (slot,))
         return tok0, kc, vc, ks, vs, pos, tok
 
-    return fn
+    return decode_prefill
 
 
 def _build_fused_decode(model, b_kv: int) -> Callable:
@@ -313,7 +315,8 @@ def _build_fused_decode(model, b_kv: int) -> Callable:
     op is row-independent so their garbage never escapes the row.
     """
 
-    def fn(weights, kc, vc, ks, vs, tok, pos, live, eos, n_steps):
+    def decode_chunk(weights, kc, vc, ks, vs, tok, pos, live, eos,
+                     n_steps):
         b = tok.shape[0]
         live_m = live > 0
         n = jnp.asarray(n_steps, jnp.int32)
@@ -342,7 +345,7 @@ def _build_fused_decode(model, b_kv: int) -> Callable:
             cond, body, carry)
         return out, i, kc, vc, ks, vs, tok, pos
 
-    return fn
+    return decode_chunk
 
 
 # the speculative executables' fixed draft-column width
@@ -367,7 +370,7 @@ def _build_spec_draft(model, b_kv: int) -> Callable:
     after the fact.
     """
 
-    def fn(weights, kc, vc, ks, vs, tok, pos, n_draft):
+    def spec_draft(weights, kc, vc, ks, vs, tok, pos, n_draft):
         b = tok.shape[0]
         n = jnp.asarray(n_draft, jnp.int32)
 
@@ -390,7 +393,7 @@ def _build_spec_draft(model, b_kv: int) -> Callable:
                  jnp.zeros((b, _SPEC_MAX_K), jnp.int32))
         return jax.lax.while_loop(cond, body, carry)[-1]
 
-    return fn
+    return spec_draft
 
 
 def _build_spec_verify(model, b_kv: int) -> Callable:
@@ -416,8 +419,8 @@ def _build_spec_verify(model, b_kv: int) -> Callable:
     the reference stream (the house invariant, extended).
     """
 
-    def fn(weights, kc, vc, ks, vs, tok, pos, live, drafts, n_draft,
-           rem, eos):
+    def spec_verify(weights, kc, vc, ks, vs, tok, pos, live, drafts,
+                    n_draft, rem, eos):
         b = tok.shape[0]
         n = jnp.asarray(n_draft, jnp.int32)
 
@@ -458,7 +461,7 @@ def _build_spec_verify(model, b_kv: int) -> Callable:
             jax.lax.while_loop(cond, body, carry)
         return out, cnt, acc, kc, vc, ks, vs, tok, pos
 
-    return fn
+    return spec_verify
 
 
 def _build_spec_round(model, b_kv: int) -> Callable:
@@ -481,14 +484,14 @@ def _build_spec_round(model, b_kv: int) -> Callable:
     draft_fn = _build_spec_draft(model, b_kv)
     verify_fn = _build_spec_verify(model, b_kv)
 
-    def fn(draft_weights, weights, kc, vc, ks, vs, tok, pos, live,
-           n_draft, rem, eos):
+    def spec_round(draft_weights, weights, kc, vc, ks, vs, tok, pos, live,
+                   n_draft, rem, eos):
         drafts = draft_fn(draft_weights, kc, vc, ks, vs, tok, pos,
                           n_draft)
         return verify_fn(weights, kc, vc, ks, vs, tok, pos, live,
                          drafts, n_draft, rem, eos)
 
-    return fn
+    return spec_round
 
 
 def _compile_spec_round(model, params, b_kv: int, batch: int,
@@ -588,6 +591,10 @@ class _Active:
     last_emit_s: float
     itls: List[float]
     on_token: Optional[Callable]
+    # host clock when the first / last token reached the host; stamped
+    # only while metrics are on (the wall TTFT and TPOT histograms)
+    first_wall_s: float = 0.0
+    last_wall_s: float = 0.0
 
 
 class _Group:
@@ -828,14 +835,20 @@ class DecodeEngine:
             qcfg = QuantConfig(bits=b_hat, scheme="uniform",
                                granularity="per-channel")
         if plan_key not in self._weights:
-            self._weights[plan_key] = fake_quantize_agent(
-                self.params, self._axes, self.cfg, qcfg, ste=False)
+            self._weights[plan_key] = self._materialize(plan_key, qcfg)
         self._classes[qos_name] = _ClassState(
             qos=qos, b_hat=b_hat, b_eff=b_eff, b_kv=b_kv,
             f=float(f) if f is not None else self.sysp.f_max,
             f_server=float(f_server) if f_server is not None
             else self.sysp.f_server_max,
             plan_key=plan_key, plan_bits=plan_bits, solution=solution)
+
+    def _materialize(self, plan_key: tuple, qcfg):
+        """The fake-quantized weight tree of one plan (set-up), its
+        device work finished inside the ``decode.materialize`` span."""
+        with self.tracer.span("decode.materialize", plan=str(plan_key)):
+            return jax.block_until_ready(fake_quantize_agent(
+                self.params, self._axes, self.cfg, qcfg, ste=False))
 
     def solution_for(self, qos_name: str):
         """The class's decode codesign solution (None when pinned)."""
@@ -942,8 +955,10 @@ class DecodeEngine:
         arr = float(arrival_s) if arrival_s is not None else self._clock
         self._queue.append(DecodeRequest(
             request_id=rid, tokens=toks, qos=qos, max_new_tokens=m,
-            arrival_s=arr))
+            arrival_s=arr, submitted_wall_s=time.monotonic()
+            if self.metrics.enabled else 0.0))
         self._on_token[rid] = on_token
+        self.tracer.instant("decode.submit", rid=rid)
         return rid
 
     @property
@@ -1059,14 +1074,20 @@ class DecodeEngine:
         step cadence (used by tests that interleave cancel/step).
         """
         out: List[DecodeResponse] = []
-        if self.in_flight == 0 and self._queue:
-            nxt = min(r.arrival_s for r in self._queue)
-            if nxt > self._clock:
-                self._clock = nxt         # fast-forward an idle engine
-        self._admit(out)
-        g = self._next_group()
-        if g is not None:
-            self._decode_round(g, out, max_decode_steps)
+        in_flight = self.in_flight
+        # the span's self time is scheduling: the admission scan, group
+        # choice, chunk length and live mask
+        args = {"pending": len(self._queue), "in_flight": in_flight} \
+            if self.tracer.enabled else {}
+        with self.tracer.span("decode.step", **args):
+            if in_flight == 0 and self._queue:
+                nxt = min(r.arrival_s for r in self._queue)
+                if nxt > self._clock:
+                    self._clock = nxt     # fast-forward an idle engine
+            self._admit(out)
+            g = self._next_group()
+            if g is not None:
+                self._decode_round(g, out, max_decode_steps)
         return out
 
     def drain(self) -> List[DecodeResponse]:
@@ -1118,16 +1139,19 @@ class DecodeEngine:
         padded = np.zeros((1, s_bucket), np.int32)
         padded[0, :p_len] = req.tokens
         exe = self._prefill_exe(c, s_bucket, g.t_bucket)
-        with self.tracer.span("decode.prefill", rid=req.request_id,
-                              qos=req.qos, s_bucket=s_bucket,
-                              t_bucket=g.t_bucket):
-            (tok0, g.k_codes, g.v_codes, g.k_scales, g.v_scales, g.pos,
-             g.tok) = exe(
-                self._weights[c.plan_key], jnp.asarray(padded),
-                jnp.asarray([p_len - 1], jnp.int32),
-                jnp.asarray(slot, jnp.int32),
-                g.k_codes, g.v_codes, g.k_scales, g.v_scales, g.pos, g.tok)
-            first = int(np.asarray(tok0)[0])
+        tr, m = self.tracer, self.metrics
+        with tr.span("decode.prefill", rid=req.request_id, qos=req.qos,
+                     s_bucket=s_bucket, t_bucket=g.t_bucket):
+            with tr.span("decode.prefill.launch"):
+                (tok0, g.k_codes, g.v_codes, g.k_scales, g.v_scales,
+                 g.pos, g.tok) = exe(
+                    self._weights[c.plan_key], jnp.asarray(padded),
+                    jnp.asarray([p_len - 1], jnp.int32),
+                    jnp.asarray(slot, jnp.int32), g.k_codes, g.v_codes,
+                    g.k_scales, g.v_scales, g.pos, g.tok)
+            with tr.span("decode.prefill.wait"):
+                first = int(np.asarray(tok0)[0])
+        t_host = time.monotonic() if m.enabled else 0.0
         # the only host<->device traffic an admission causes: the padded
         # prompt + two scalars in, the streamed first token out
         self._h2d += padded.nbytes + 8
@@ -1147,9 +1171,9 @@ class DecodeEngine:
                       admitted_s=self._clock,
                       ttft_s=self._clock - req.arrival_s,
                       last_emit_s=self._clock, itls=[],
-                      on_token=self._on_token.pop(req.request_id, None))
+                      on_token=self._on_token.pop(req.request_id, None),
+                      first_wall_s=t_host, last_wall_s=t_host)
         g.slots[slot] = act
-        m = self.metrics
         if m.enabled:
             m.counter("decode.prefills", engine="DecodeEngine",
                       qos=req.qos).inc()
@@ -1157,11 +1181,14 @@ class DecodeEngine:
                       engine="DecodeEngine").inc(padded.nbytes + 8)
             m.counter("decode.d2h_bytes", engine="DecodeEngine").inc(4)
             m.histogram("decode.ttft_s", engine="DecodeEngine",
-                        qos=req.qos).observe(act.ttft_s)
-        if act.on_token is not None:
-            act.on_token(req.request_id, first, self._clock)
-        if len(act.generated) >= req.max_new_tokens:
-            out.append(self._retire(g, slot))
+                        qos=req.qos).observe(t_host - req.submitted_wall_s)
+        with tr.span("decode.emit") as sp:
+            if act.on_token is not None:
+                act.on_token(req.request_id, first, self._clock)
+            if len(act.generated) >= req.max_new_tokens:
+                out.append(self._retire(g, slot))
+            if tr.enabled:
+                sp.set(tokens=1)
 
     def _next_group(self) -> Optional[_Group]:
         for _ in range(len(self._rr)):
@@ -1201,22 +1228,29 @@ class DecodeEngine:
         live[live_rows] = 1
         eos = self.eos_id if self.eos_id is not None else -1
         exe = self._decode_exe(c, g.t_bucket)
-        with self.tracer.span("decode.chunk", qos=g.qos_name,
-                              live_rows=len(live_rows),
-                              t_bucket=g.t_bucket, max_steps=k):
-            (blk, steps, g.k_codes, g.v_codes, g.k_scales, g.v_scales,
-             g.tok, g.pos) = exe(
-                self._weights[c.plan_key], g.k_codes, g.v_codes,
-                g.k_scales, g.v_scales, g.tok, g.pos, jnp.asarray(live),
-                jnp.asarray(eos, jnp.int32), jnp.asarray(k, jnp.int32))
-            blk = np.asarray(blk)
-            steps = int(steps)
+        tr = self.tracer
+        with tr.span("decode.chunk", qos=g.qos_name,
+                     live_rows=len(live_rows), t_bucket=g.t_bucket,
+                     max_steps=k) as chunk:
+            with tr.span("decode.chunk.launch"):
+                (blk, steps, g.k_codes, g.v_codes, g.k_scales, g.v_scales,
+                 g.tok, g.pos) = exe(
+                    self._weights[c.plan_key], g.k_codes, g.v_codes,
+                    g.k_scales, g.v_scales, g.tok, g.pos,
+                    jnp.asarray(live), jnp.asarray(eos, jnp.int32),
+                    jnp.asarray(k, jnp.int32))
+            with tr.span("decode.chunk.wait"):
+                blk = np.asarray(blk)
+                steps = int(steps)
+            if tr.enabled:
+                chunk.set(steps=steps)
         # the only host<->device traffic a chunk causes, independent of
         # the cache size: the live mask + two scalars in, the token
         # block + step count out
         self._h2d += live.nbytes + 8
         self._d2h += blk.nbytes + 4
         m = self.metrics
+        t_host = time.monotonic() if m.enabled else 0.0
         if m.enabled:
             m.counter("decode.chunks", engine="DecodeEngine",
                       qos=g.qos_name).inc()
@@ -1234,24 +1268,31 @@ class DecodeEngine:
         self._rounds += steps
         finished: List[int] = []
         done = set()
-        for j in range(steps):
-            t_emit = clock0 + (j + 1) * t_round
-            for i in live_rows:
-                if i in done:
-                    continue
-                act = g.slots[i]
-                tok_ij = int(blk[i, j])
-                act.generated.append(tok_ij)
-                act.itls.append(t_emit - act.last_emit_s)
-                act.last_emit_s = t_emit
-                if act.on_token is not None:
-                    act.on_token(act.req.request_id, tok_ij, t_emit)
-                if (self.eos_id is not None and tok_ij == self.eos_id) \
-                        or len(act.generated) >= act.req.max_new_tokens:
-                    done.add(i)
-                    finished.append(i)
-        for i in finished:
-            out.append(self._retire(g, i))
+        emitted = 0
+        with tr.span("decode.emit") as sp:
+            for j in range(steps):
+                t_emit = clock0 + (j + 1) * t_round
+                for i in live_rows:
+                    if i in done:
+                        continue
+                    act = g.slots[i]
+                    tok_ij = int(blk[i, j])
+                    act.generated.append(tok_ij)
+                    act.itls.append(t_emit - act.last_emit_s)
+                    act.last_emit_s = t_emit
+                    emitted += 1
+                    if act.on_token is not None:
+                        act.on_token(act.req.request_id, tok_ij, t_emit)
+                    if (self.eos_id is not None
+                            and tok_ij == self.eos_id) \
+                            or len(act.generated) >= act.req.max_new_tokens:
+                        done.add(i)
+                        finished.append(i)
+                        act.last_wall_s = t_host
+            for i in finished:
+                out.append(self._retire(g, i))
+            if tr.enabled:
+                sp.set(tokens=emitted)
 
     def _retire(self, g: _Group, slot: int,
                 cancelled: bool = False) -> DecodeResponse:
@@ -1281,12 +1322,14 @@ class DecodeEngine:
                       qos=act.req.qos).inc()
             m.counter("decode.tokens", engine="DecodeEngine",
                       qos=act.req.qos).inc(len(act.generated))
-            # per-token ITL, observed in one batch at retirement so the
-            # hot emission loop above stays instrument-free
-            h = m.histogram("decode.itl_s", engine="DecodeEngine",
-                            qos=act.req.qos)
-            for v in act.itls:
-                h.observe(v)
+            # wall time per output token after the first, as the
+            # benchmark defines it: (last - first token on the host) /
+            # (n - 1), observed once per request at retirement
+            n = len(act.generated)
+            if not cancelled and n > 1:
+                m.histogram("decode.tpot_s", engine="DecodeEngine",
+                            qos=act.req.qos).observe(
+                    (act.last_wall_s - act.first_wall_s) / (n - 1))
         return DecodeResponse(
             request_id=act.req.request_id, qos=act.req.qos,
             tokens=np.asarray(act.generated, np.int32),

@@ -50,6 +50,7 @@ four:
 from __future__ import annotations
 
 import dataclasses
+import time
 from typing import Callable, Dict, List, Optional, Sequence
 
 import jax.numpy as jnp
@@ -64,7 +65,6 @@ from repro.obs import ReportBase
 
 from .decode_engine import (_SPEC_MAX_K, DecodeEngine, DecodeResponse,
                             _ClassState, _compile_spec_round, _Group)
-from .qat import fake_quantize_agent
 from .serve_engine import QosClass
 
 __all__ = [
@@ -204,10 +204,9 @@ class SpeculativeDecodeEngine(DecodeEngine):
                                     solution=solution)
         dk = ("uniform", b_draft)
         if dk not in self._weights:
-            self._weights[dk] = fake_quantize_agent(
-                self.params, self._axes, self.cfg,
-                QuantConfig(bits=b_draft, scheme="uniform",
-                            granularity="per-channel"), ste=False)
+            self._weights[dk] = self._materialize(
+                dk, QuantConfig(bits=b_draft, scheme="uniform",
+                                granularity="per-channel"))
         self._spec[qos_name] = _SpecState(b_draft=b_draft, k=k,
                                           plan_key=dk)
 
@@ -274,19 +273,22 @@ class SpeculativeDecodeEngine(DecodeEngine):
         live[live_rows] = 1
         eos = self.eos_id if self.eos_id is not None else -1
         exe = self._spec_round_exe(c, g.t_bucket)
-        with self.tracer.span("decode.spec_round", qos=g.qos_name,
-                              live_rows=len(live_rows),
-                              t_bucket=g.t_bucket, n_draft=n_draft):
-            (blk, cnt, acc, g.k_codes, g.v_codes, g.k_scales,
-             g.v_scales, g.tok, g.pos) = exe(
-                self._weights[sp.plan_key], self._weights[c.plan_key],
-                g.k_codes, g.v_codes, g.k_scales, g.v_scales, g.tok,
-                g.pos, jnp.asarray(live),
-                jnp.asarray(n_draft, jnp.int32),
-                jnp.asarray(rem), jnp.asarray(eos, jnp.int32))
-            blk = np.asarray(blk)
-            cnt = np.asarray(cnt)
-            acc = np.asarray(acc)
+        tr = self.tracer
+        with tr.span("decode.spec_round", qos=g.qos_name,
+                     live_rows=len(live_rows), t_bucket=g.t_bucket,
+                     n_draft=n_draft):
+            with tr.span("decode.spec_round.launch"):
+                (blk, cnt, acc, g.k_codes, g.v_codes, g.k_scales,
+                 g.v_scales, g.tok, g.pos) = exe(
+                    self._weights[sp.plan_key], self._weights[c.plan_key],
+                    g.k_codes, g.v_codes, g.k_scales, g.v_scales, g.tok,
+                    g.pos, jnp.asarray(live),
+                    jnp.asarray(n_draft, jnp.int32),
+                    jnp.asarray(rem), jnp.asarray(eos, jnp.int32))
+            with tr.span("decode.spec_round.wait"):
+                blk = np.asarray(blk)
+                cnt = np.asarray(cnt)
+                acc = np.asarray(acc)
         # host traffic: masks + scalars in, the delivered block out
         # (drafts never leave the device — they live and die inside the
         # fused round executable)
@@ -307,6 +309,7 @@ class SpeculativeDecodeEngine(DecodeEngine):
         self._spec_accepted += accepted
         self._spec_delivered += delivered
         m = self.metrics
+        t_host = time.monotonic() if m.enabled else 0.0
         if m.enabled:
             m.counter("decode.spec_rounds",
                       engine="SpeculativeDecodeEngine",
@@ -330,21 +333,25 @@ class SpeculativeDecodeEngine(DecodeEngine):
         # output is delivered in one burst at the round boundary
         t_emit = self._clock
         finished: List[int] = []
-        for i in live_rows:
-            act = g.slots[i]
-            for j in range(int(cnt[i])):
-                tok_ij = int(blk[i, j])
-                act.generated.append(tok_ij)
-                act.itls.append(t_emit - act.last_emit_s)
-                act.last_emit_s = t_emit
-                if act.on_token is not None:
-                    act.on_token(act.req.request_id, tok_ij, t_emit)
-            last = act.generated[-1]
-            if (self.eos_id is not None and last == self.eos_id) \
-                    or len(act.generated) >= act.req.max_new_tokens:
-                finished.append(i)
-        for i in finished:
-            out.append(self._retire(g, i))
+        with tr.span("decode.emit") as emit:
+            for i in live_rows:
+                act = g.slots[i]
+                for j in range(int(cnt[i])):
+                    tok_ij = int(blk[i, j])
+                    act.generated.append(tok_ij)
+                    act.itls.append(t_emit - act.last_emit_s)
+                    act.last_emit_s = t_emit
+                    if act.on_token is not None:
+                        act.on_token(act.req.request_id, tok_ij, t_emit)
+                last = act.generated[-1]
+                if (self.eos_id is not None and last == self.eos_id) \
+                        or len(act.generated) >= act.req.max_new_tokens:
+                    finished.append(i)
+                    act.last_wall_s = t_host
+            for i in finished:
+                out.append(self._retire(g, i))
+            if tr.enabled:
+                emit.set(tokens=delivered)
 
     # ------------------------------------------------------------------
     # billing
