@@ -39,7 +39,8 @@ import numpy as np  # noqa: E402
 
 from repro.configs.qwen2_0_5b import FULL as CFG  # noqa: E402
 from repro.kernels import ops, ref  # noqa: E402
-from repro.kernels.decode_attn import quantized_decode_attention  # noqa: E402
+from repro.kernels.decode_attn import (  # noqa: E402
+    cache_layout, quantized_decode_attention)
 from repro.kernels.pallas_env import use_interpret  # noqa: E402
 from repro.kernels.quantize import kv_quantize  # noqa: E402
 from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
@@ -99,7 +100,7 @@ def highest(fn, *args):
 def phase_kernels() -> None:
     """(b) the four serving-path kernels at qwen2-0.5b widths."""
     d, f = CFG.d_model, CFG.d_ff
-    key = iter(jax.random.split(jax.random.PRNGKey(0), 8))
+    key = iter(jax.random.split(jax.random.PRNGKey(0), 10))
     x = jax.random.normal(next(key), (128, d), jnp.float32)
     x_ff = jax.random.normal(next(key), (128, f), jnp.float32)
 
@@ -147,17 +148,23 @@ def phase_kernels() -> None:
         report(f"qmm int4 [128, {k}] @ [{k}, {n}]", rel_err(out, want),
                KERNEL_RTOL, t_c, t_r, custom)
 
-    # quantized decode attention over the largest warmed cache bucket
+    # quantized decode attention over the largest warmed cache bucket:
+    # one decode step's write and attend, on layer 1 of a 3-deep stack
     b, t, h, kv, dh = 4, 256, CFG.n_heads, CFG.n_kv_heads, CFG.head_dim
     q = jax.random.normal(next(key), (b, 1, h, dh), jnp.float32)
-    kf = jax.random.normal(next(key), (b, t, kv, dh), jnp.float32)
-    vf = jax.random.normal(next(key), (b, t, kv, dh), jnp.float32)
-    kc, ks = kv_quantize(kf, 8)
-    vc, vs = kv_quantize(vf, 8)
+    kc, ks = cache_layout(*kv_quantize(
+        jax.random.normal(next(key), (3, b, t, kv, dh), jnp.float32), 8))
+    vc, vs = cache_layout(*kv_quantize(
+        jax.random.normal(next(key), (3, b, t, kv, dh), jnp.float32), 8))
+    (kn, ksn), (vn, vsn) = (kv_quantize(
+        jax.random.normal(next(key), (b, kv, dh), jnp.float32), 8)
+        for _ in range(2))
     lens = jnp.asarray([1, 77, 200, 256], jnp.int32)
-    out, t_c, t_r, custom = run_compiled(quantized_decode_attention,
-                                         q, kc, vc, ks, vs, lens)
-    want = highest(ref.decode_attention_ref, q, kc, vc, ks, vs, lens)
+    layer = jnp.asarray(1, jnp.int32)
+    (out, stack), t_c, t_r, custom = run_compiled(
+        quantized_decode_attention, q, kc, vc, ks, vs, lens, layer,
+        (kn, vn, ksn, vsn))
+    want = highest(ref.decode_attention_ref, q, *stack, lens, 1)
     report(f"decode_attention B={b} T={t} H={h} KV={kv} dh={dh}",
            rel_err(out, want), KERNEL_RTOL, t_c, t_r, custom)
 

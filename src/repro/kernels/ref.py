@@ -87,22 +87,24 @@ def qmm_int4_ref(x: jnp.ndarray, packed: jnp.ndarray,
 
 
 def decode_attention_ref(q, k_codes, v_codes, k_scales, v_scales,
-                         cache_len, *, window: int = 0):
+                         cache_len, layer=0, *, window: int = 0):
     """Oracle for the quantized decode attention: dequantize the whole
-    cache, then one masked softmax per query head.
+    layer, then one masked softmax per query head.
 
-    q [B, 1, H, dh]; codes [B, T, KV, dh]; scales [B, T, KV]; cache_len
-    [B] (positions at or beyond it are masked, and with ``window`` > 0
-    so are those more than ``window`` back).  Returns [B, 1, H, dh] f32.
+    q [B, 1, H, dh]; codes [L, B, KV, dh, T] (the decode engine's
+    head-major stack); scales [L, B, KV, 1, T]; ``layer`` picks the
+    layer; cache_len [B] (positions at or beyond it are masked, and with
+    ``window`` > 0 so are those more than ``window`` back).  Returns
+    [B, 1, H, dh] f32.
     """
     b, _, h, dh = q.shape
-    t, kv = k_codes.shape[1], k_codes.shape[2]
-    k = k_codes.astype(jnp.float32) * k_scales[..., None]
-    v = v_codes.astype(jnp.float32) * v_scales[..., None]
+    kv, t = k_codes.shape[2], k_codes.shape[4]
+    k = k_codes[layer].astype(jnp.float32) * k_scales[layer]
+    v = v_codes[layer].astype(jnp.float32) * v_scales[layer]
     # GQA: query head i reads KV head i // (H / KV)
-    k = jnp.repeat(k, h // kv, axis=2)                   # [B, T, H, dh]
-    v = jnp.repeat(v, h // kv, axis=2)
-    s = jnp.einsum("bhd,bthd->bht", q[:, 0].astype(jnp.float32), k) \
+    k = jnp.repeat(k, h // kv, axis=1)                   # [B, H, dh, T]
+    v = jnp.repeat(v, h // kv, axis=1)
+    s = jnp.einsum("bhd,bhdt->bht", q[:, 0].astype(jnp.float32), k) \
         * dh ** -0.5
     pos = jnp.arange(t)[None, None, :]
     n = jnp.reshape(cache_len, (-1, 1, 1))
@@ -110,4 +112,4 @@ def decode_attention_ref(q, k_codes, v_codes, k_scales, v_scales,
     if window > 0:
         valid &= pos >= n - window
     p = jax.nn.softmax(jnp.where(valid, s, -jnp.inf), axis=-1)
-    return jnp.einsum("bht,bthd->bhd", p, v)[:, None]
+    return jnp.einsum("bht,bhdt->bhd", p, v)[:, None]

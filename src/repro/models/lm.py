@@ -346,46 +346,44 @@ class DecoderLM:
     def decode_step_q(self, params, qcache, batch, *, b_kv: int):
         """One token straight over the *quantized* cache (DESIGN.md §13).
 
-        ``qcache`` is the decode engine's device-resident container:
-        ``k_codes``/``v_codes`` [L, B, T, KV, dh] (int8 codes for
-        b_kv < 16, the raw cfg.dtype container otherwise),
-        ``k_scales``/``v_scales`` [L, B, T, KV] f32 (ones for raw), plus
-        per-row ``len``.  Unlike :meth:`decode_step`, the cache is never
-        dequantized wholesale: the fresh entry is quantized *before* it
-        is written (so this step's own attention reads it through the
-        same dequant map every later step will), and attention runs via
-        :func:`quantized_decode_attention`, which dequantizes per-tile
-        in VMEM.  b_kv >= 16 stores raw values with unit scales — an
-        exact path through the identical kernel.
+        ``qcache`` is the decode engine's device-resident container,
+        head-major with positions minor: ``k_codes``/``v_codes``
+        [L, B, KV, dh, T] (int8 codes for b_kv < 16, the raw cfg.dtype
+        container otherwise), ``k_scales``/``v_scales`` [L, B, KV, 1, T]
+        f32 (ones for raw), plus per-row ``len``.  Unlike
+        :meth:`decode_step`, the cache is never dequantized wholesale,
+        and never sliced, transposed or restacked: the layer loop scans
+        only the layer parameters and carries the whole stack, and per
+        layer :func:`quantized_decode_attention` writes each row's fresh
+        entry in place at position ``pos`` and attends over the layer
+        where it lies.  The fresh entry is quantized *before* it is
+        written, so this step's own attention reads it through the same
+        dequant map every later step will.  b_kv >= 16 stores raw values
+        with unit scales — an exact path through the identical kernel.
         """
         cfg = self.cfg
         tok, pos = batch["token"], batch["pos"]
         x = L.embed_tokens(params["embed"], tok, jnp.dtype(cfg.dtype))
         positions = pos[:, None]
+        b = x.shape[0]
 
-        def write_row(c, entry, pp):
-            # one row: entry [1, ...] into cache [T, ...] at position pp
-            return jax.lax.dynamic_update_slice(
-                c, entry, (pp,) + (0,) * (c.ndim - 1))
-
-        def step(x, lp_and_cache):
-            lp, kc, vc, ksc, vsc = lp_and_cache
+        def step(carry, lp_and_layer):
+            x, kc, vc, ksc, vsc = carry
+            lp, layer = lp_and_layer
             h = L.apply_norm(cfg, x, lp["ln1"])
             q, k, v = L.qkv_project(cfg, lp["attn"], h, positions)
-            b = x.shape[0]
+            k, v = k[:, 0], v[:, 0]                     # [B, KV, dh]
             if b_kv < 16:
                 k_new, ks_new = kv_quantize(k, b_kv)
                 v_new, vs_new = kv_quantize(v, b_kv)
             else:
-                k_new, v_new = k.astype(kc.dtype), v.astype(vc.dtype)
+                k_new, v_new = k, v
                 ks_new = jnp.ones(k.shape[:-1], jnp.float32)
                 vs_new = jnp.ones(v.shape[:-1], jnp.float32)
-            kc = jax.vmap(write_row)(kc, k_new.astype(kc.dtype), pos)
-            vc = jax.vmap(write_row)(vc, v_new.astype(vc.dtype), pos)
-            ksc = jax.vmap(write_row)(ksc, ks_new, pos)
-            vsc = jax.vmap(write_row)(vsc, vs_new, pos)
-            attn = quantized_decode_attention(
-                q, kc, vc, ksc, vsc, pos + 1, window=cfg.sliding_window)
+            # the kernel writes the entry where it lies, then attends
+            attn, (kc, vc, ksc, vsc) = quantized_decode_attention(
+                q, kc, vc, ksc, vsc, pos + 1, layer,
+                (k_new, v_new, ks_new, vs_new), window=cfg.sliding_window)
             x = x + attn.reshape(b, 1, cfg.q_dim) \
                 @ lp["attn"]["wo"].astype(x.dtype)
             h2 = L.apply_norm(cfg, x, lp["ln2"])
@@ -396,12 +394,12 @@ class DecoderLM:
                                    group_size=min(1024, b))
             else:
                 y = L.apply_mlp(cfg, lp["ffn"], h2)
-            return x + y, (kc, vc, ksc, vsc)
+            return (x + y, kc, vc, ksc, vsc), None
 
-        x, (ks, vs, kss, vss) = jax.lax.scan(
-            step, x, (params["layers"], qcache["k_codes"],
-                      qcache["v_codes"], qcache["k_scales"],
-                      qcache["v_scales"]))
+        carry = (x, qcache["k_codes"], qcache["v_codes"],
+                 qcache["k_scales"], qcache["v_scales"])
+        (x, ks, vs, kss, vss), _ = jax.lax.scan(
+            step, carry, (params["layers"], jnp.arange(cfg.n_layers)))
         x = L.apply_norm(cfg, x, params["final_norm"])
         logits = L.unembed(cfg, params["embed"], x)[:, 0]
         new_cache = {"k_codes": ks, "v_codes": vs, "k_scales": kss,

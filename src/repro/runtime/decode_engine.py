@@ -84,6 +84,7 @@ from repro.core.cost_model import (SystemParams, agent_delay, agent_energy,
 from repro.core.quantization import QuantConfig, QuantPlan
 from repro.core.rate_distortion import exponential_mle
 from repro.kernels.bucketing import DEFAULT_SEQ_BASE, seq_bucket, seq_ladder
+from repro.kernels.decode_attn import cache_layout
 from repro.kernels.quantize import kv_cache_bytes, kv_quantize
 from repro.obs import NULL_METRICS, NULL_TRACER, ReportBase
 
@@ -123,7 +124,8 @@ def decode_protocol_gap(model) -> Optional[str]:
 
     Requires the full DecoderLM decode protocol — ``prefill`` /
     ``init_cache`` / ``decode_step`` / ``decode_step_q`` — *and* the
-    [L, B, T, KV, dh] KV-cache layout this engine's slot arrays assume.
+    [L, B, T, KV, dh] KV cache a prefill returns, which the engine turns
+    into its head-major slot arrays.
     Hybrid/xLSTM/enc-dec families expose same-named hooks over different
     state shapes; they are rejected here, not by a shape error three
     calls in.
@@ -263,11 +265,13 @@ def _build_prefill(model, b_kv: int) -> Callable:
 
     (weights, tokens [1, S], last_idx [1], slot [], k_codes, v_codes,
     k_scales, v_scales, pos [B], tok [B]) -> (first greedy token [1],
-    updated buffers).  The prompt's cache block is quantized and written
-    into decode slot ``slot`` of the group's device-resident buffers
-    inside one executable — the quantization arithmetic is in-trace, so
-    engine and reference share it exactly, and the cache block never
-    visits the host.  Buffer positions past the prompt keep the previous
+    updated buffers).  The prompt's cache block is quantized, turned
+    head-major (only the prompt's own ``[L, 1, S, KV, dh]`` block is
+    transposed, never the slot buffers) and written into decode slot
+    ``slot`` of the group's device-resident buffers inside one
+    executable — the quantization arithmetic is in-trace, so engine and
+    reference share it exactly, and the cache block never visits the
+    host.  Buffer positions past the prompt keep the previous
     occupant's stale entries: attention masks positions >= the row's
     cache length, so they are never read before this occupant overwrites
     them token by token.
@@ -288,11 +292,13 @@ def _build_prefill(model, b_kv: int) -> Callable:
             kq, ksn = kv_quantize(k, b_kv)
             vq, vsn = kv_quantize(v, b_kv)
             kq, vq = kq.astype(kc.dtype), vq.astype(vc.dtype)
+        kq, ksn = cache_layout(kq, ksn)
+        vq, vsn = cache_layout(vq, vsn)
         at5 = (0, slot, 0, 0, 0)
         kc = jax.lax.dynamic_update_slice(kc, kq, at5)
         vc = jax.lax.dynamic_update_slice(vc, vq, at5)
-        ks = jax.lax.dynamic_update_slice(ks, ksn, at5[:-1])
-        vs = jax.lax.dynamic_update_slice(vs, vsn, at5[:-1])
+        ks = jax.lax.dynamic_update_slice(ks, ksn, at5)
+        vs = jax.lax.dynamic_update_slice(vs, vsn, at5)
         pos = jax.lax.dynamic_update_slice(pos, last_idx + 1, (slot,))
         tok = jax.lax.dynamic_update_slice(tok, tok0, (slot,))
         return tok0, kc, vc, ks, vs, pos, tok
@@ -312,7 +318,10 @@ def _build_fused_decode(model, b_kv: int) -> Callable:
     greedy tokens are always >= 0).  The §10 isolation argument makes
     each iteration one fixed XLA sub-computation, so chunk boundaries
     cannot change bits; dead slots (live = 0) still compute, but every
-    op is row-independent so their garbage never escapes the row.
+    op is row-independent so their garbage never escapes the row.  The
+    cache buffers ride the loop carry whole: each step writes its new
+    entries in place and the kernel reads them where they lie, so no
+    step moves a cache-sized array (``tests/test_decode_inplace.py``).
     """
 
     def decode_chunk(weights, kc, vc, ks, vs, tok, pos, live, eos,
@@ -436,11 +445,10 @@ def _build_spec_verify(model, b_kv: int) -> Callable:
                 {"token": tok[:, None], "pos": pos}, b_kv=b_kv)
             g = jnp.argmax(logits, axis=-1).astype(jnp.int32)
             m5 = act[None, :, None, None, None]
-            m4 = act[None, :, None, None]
             kc = jnp.where(m5, qc["k_codes"], kc)
             vc = jnp.where(m5, qc["v_codes"], vc)
-            ks = jnp.where(m4, qc["k_scales"], ks)
-            vs = jnp.where(m4, qc["v_scales"], vs)
+            ks = jnp.where(m5, qc["k_scales"], ks)
+            vs = jnp.where(m5, qc["v_scales"], vs)
             pos = jnp.where(act, qc["len"], pos)
             tok = jnp.where(act, g, tok)
             # all active rows share emission column i (== their cnt);
@@ -531,11 +539,20 @@ def _container_dtype(cfg, b_kv: int) -> np.dtype:
     return np.dtype("int8") if b_kv < 16 else np.dtype(cfg.dtype)
 
 
+def _cache_shapes(cfg, batch: int, t_bucket: int):
+    """(codes, scales) shapes of a slot block: head-major codes
+    [L, B, KV, dh, T] and scales [L, B, KV, 1, T].  B stays axis 1 (slot
+    slices, row masks); KV next makes the kernel's [L, B*KV, ...] views
+    reshapes; positions minor is the layout the TPU gives such an array
+    anyway, so nothing relays it out (DESIGN.md §13)."""
+    codes = (cfg.n_layers, batch, cfg.n_kv_heads, cfg.head_dim, t_bucket)
+    return codes, codes[:3] + (1, t_bucket)
+
+
 def _cache_sds(cfg, b_kv: int, batch: int, t_bucket: int):
-    cont = _container_dtype(cfg, b_kv)
-    shape = (cfg.n_layers, batch, t_bucket, cfg.n_kv_heads, cfg.head_dim)
-    codes = jax.ShapeDtypeStruct(shape, cont)
-    scales = jax.ShapeDtypeStruct(shape[:-1], jnp.float32)
+    shape, s_shape = _cache_shapes(cfg, batch, t_bucket)
+    codes = jax.ShapeDtypeStruct(shape, _container_dtype(cfg, b_kv))
+    scales = jax.ShapeDtypeStruct(s_shape, jnp.float32)
     vec = jax.ShapeDtypeStruct((batch,), jnp.int32)
     return codes, scales, vec
 
@@ -614,12 +631,11 @@ class _Group:
         self.qos_name = qos_name
         self.t_bucket = int(t_bucket)
         cont = _container_dtype(cfg, b_kv)
-        shape = (cfg.n_layers, max_batch, t_bucket, cfg.n_kv_heads,
-                 cfg.head_dim)
+        shape, s_shape = _cache_shapes(cfg, max_batch, t_bucket)
         self.k_codes = jnp.zeros(shape, cont)
         self.v_codes = jnp.zeros(shape, cont)
-        self.k_scales = jnp.ones(shape[:-1], jnp.float32)
-        self.v_scales = jnp.ones(shape[:-1], jnp.float32)
+        self.k_scales = jnp.ones(s_shape, jnp.float32)
+        self.v_scales = jnp.ones(s_shape, jnp.float32)
         self.pos = jnp.zeros((max_batch,), jnp.int32)
         self.tok = jnp.zeros((max_batch,), jnp.int32)
         self.slots: List[Optional[_Active]] = [None] * max_batch
@@ -1457,11 +1473,11 @@ def greedy_decode_reference(model, weights, tokens, max_new_tokens: int, *,
         s_bucket = int(seq_bucket(p_len, seq_bucket_base))
         padded = np.zeros((1, s_bucket), np.int32)
         padded[0, :p_len] = toks
-        shape = (cfg.n_layers, 1, t_bucket, cfg.n_kv_heads, cfg.head_dim)
+        shape, s_shape = _cache_shapes(cfg, 1, t_bucket)
         k_codes = jnp.zeros(shape, cont)
         v_codes = jnp.zeros(shape, cont)
-        k_scales = jnp.ones(shape[:-1], jnp.float32)
-        v_scales = jnp.ones(shape[:-1], jnp.float32)
+        k_scales = jnp.ones(s_shape, jnp.float32)
+        v_scales = jnp.ones(s_shape, jnp.float32)
         pos = jnp.zeros((1,), jnp.int32)
         tok = jnp.zeros((1,), jnp.int32)
         exe = cache.get(

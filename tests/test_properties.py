@@ -191,26 +191,30 @@ def test_cache_bucket_padding_is_attention_invisible(b_kv, dh, len0, len1,
     while staying bitwise-comparable to the sequential reference."""
     import jax.numpy as jnp
 
-    from repro.kernels.decode_attn import quantized_decode_attention
+    from repro.kernels.decode_attn import (cache_layout,
+                                           quantized_decode_attention)
 
     t = 32
     rng = np.random.default_rng(seed)
-    q = jnp.asarray(rng.standard_normal((2, 1, 4, dh)), jnp.float32)
-    k = jnp.asarray(rng.standard_normal((2, t, 2, dh)), jnp.float32)
-    v = jnp.asarray(rng.standard_normal((2, t, 2, dh)), jnp.float32)
-    if b_kv < 16:
-        kc, ks = kv_quantize(k, b_kv)
-        vc, vs = kv_quantize(v, b_kv)
-    else:
-        kc, vc = k, v
-        ks = jnp.ones(k.shape[:-1], jnp.float32)
-        vs = jnp.ones(v.shape[:-1], jnp.float32)
+    normal = lambda *s: jnp.asarray(rng.standard_normal(s), jnp.float32)
+
+    def quantize(x):
+        if b_kv >= 16:
+            return x, jnp.ones(x.shape[:-1], jnp.float32)
+        return kv_quantize(x, b_kv)
+
+    q = normal(2, 1, 4, dh)
+    # the engine's one-layer stack: codes [1, B, KV, dh, T]
+    kc, ks = cache_layout(*quantize(normal(1, 2, t, 2, dh)))
+    vc, vs = cache_layout(*quantize(normal(1, 2, t, 2, dh)))
+    (kn, ksn), (vn, vsn) = (quantize(normal(2, 2, dh)) for _ in range(2))
     lens = jnp.asarray([len0, len1], jnp.int32)
-    pad = [(0, 0), (0, grow), (0, 0), (0, 0)]
-    out = quantized_decode_attention(q, kc, vc, ks, vs, lens, block_t=16)
-    out_pad = quantized_decode_attention(
-        q, jnp.pad(kc, pad), jnp.pad(vc, pad),
-        jnp.pad(ks, pad[:-1]), jnp.pad(vs, pad[:-1]), lens, block_t=16)
+    pad = [(0, 0)] * 4 + [(0, grow)]
+    out, _ = quantized_decode_attention(
+        q, kc, vc, ks, vs, lens, 0, (kn, vn, ksn, vsn), block_t=16)
+    out_pad, _ = quantized_decode_attention(
+        q, *(jnp.pad(c, pad) for c in (kc, vc, ks, vs)), lens, 0,
+        (kn, vn, ksn, vsn), block_t=16)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(out_pad))
 
 

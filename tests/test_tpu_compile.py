@@ -94,10 +94,80 @@ def test_group_quantize_compiles(one_chip, k, n):
     (256, jnp.float32),         # ... and the raw b_kv >= 16 container
 ])
 def test_decode_attention_compiles(one_chip, t, container):
+    """The decode step's form: new entries written into one layer of
+    the stack in place (cache operands aliased to outputs), then
+    attended."""
     b, h, kv, dh = DECODE_BATCH, QWEN.n_heads, QWEN.n_kv_heads, QWEN.head_dim
-    _compile(one_chip,
-             lambda q, kc, vc, ks, vs, n: quantized_decode_attention(
-                 q, kc, vc, ks, vs, n, interpret=False),
-             ((b, 1, h, dh), jnp.float32), ((b, t, kv, dh), container),
-             ((b, t, kv, dh), container), ((b, t, kv), jnp.float32),
-             ((b, t, kv), jnp.float32), ((b,), jnp.int32))
+    n_l = QWEN.n_layers
+    exe = _compile(
+        one_chip,
+        lambda q, kc, vc, ks, vs, n, at, kn, vn, ksn, vsn:
+            quantized_decode_attention(q, kc, vc, ks, vs, n, at,
+                                       (kn, vn, ksn, vsn), interpret=False),
+        ((b, 1, h, dh), jnp.float32), ((n_l, b, kv, dh, t), container),
+        ((n_l, b, kv, dh, t), container), ((n_l, b, kv, 1, t), jnp.float32),
+        ((n_l, b, kv, 1, t), jnp.float32), ((b,), jnp.int32),
+        ((), jnp.int32), ((b, kv, dh), container), ((b, kv, dh), container),
+        ((b, kv), jnp.float32), ((b, kv), jnp.float32))
+    assert "output_to_operand_aliasing" in exe.as_text()
+
+
+# the long-context cell's slot block: 16 slots x 4096 positions, where a
+# cache stays in HBM (a small one the compiler may stage whole in VMEM)
+CELL_BATCH, CELL_T = 16, 4096
+
+
+@pytest.mark.parametrize("kv,heads,d_model", [
+    (4, 4, 256),            # MHA, as stablelm: every head its own KV
+    (2, 14, 896),           # qwen2's GQA grouping: 14 heads over 2
+])
+def test_fused_decode_updates_cache_in_place(one_chip, monkeypatch, kv,
+                                             heads, d_model):
+    """The whole fused decode chunk, compiled for the chip: no copy,
+    transpose or dynamic slice of one layer's codes or of the stack
+    (the layer slice, head transpose, restack and loop-carry copies the
+    step once made every token), and temporaries under one layer's
+    codes (DESIGN.md §13)."""
+    import dataclasses
+    import re
+
+    from repro.configs.stablelm_3b import FULL
+    from repro.kernels import decode_attn
+    from repro.models.lm import DecoderLM
+    from repro.runtime.decode_engine import _build_fused_decode, _cache_sds
+
+    # the model's kernel call asks the backend, which here is the CPU
+    monkeypatch.setattr(decode_attn, "use_interpret", lambda: False)
+    cfg = dataclasses.replace(FULL, d_model=d_model, n_heads=heads,
+                              n_kv_heads=kv, head_dim=64, n_layers=3,
+                              d_ff=512, vocab_size=512, split_layer=1)
+    model = DecoderLM(cfg)
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip), tree)
+
+    params = jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0)))
+    codes, scales, vec = on_chip(_cache_sds(cfg, 8, CELL_BATCH, CELL_T))
+    scalar = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    exe = jax.jit(_build_fused_decode(model, 8),
+                  donate_argnums=(1, 2, 3, 4, 5, 6)).lower(
+        on_chip(params), codes, codes, scales, scales, vec, vec, vec,
+        scalar, scalar).compile()
+    text = exe.as_text()
+    assert "tpu_custom_call" in text
+    layer = CELL_BATCH * CELL_T * kv * cfg.head_dim
+    moves = []
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%(\S+) = \w+\[([\d,]*)\]", line)
+        if not m or not any(op in m.group(1) for op in
+                            ("copy", "transpose", "dynamic-slice")):
+            continue
+        size = 1
+        for d in filter(None, m.group(2).split(",")):
+            size *= int(d)
+        if size in (layer, cfg.n_layers * layer):
+            moves.append(m.group(1))
+    assert moves == []
+    assert exe.memory_analysis().temp_size_in_bytes < layer
