@@ -39,14 +39,12 @@ import run
 
 def read_seed(cell, cfg, seed, seconds, compile_cache,
               control: bool) -> dict:
-    import jax
-    from repro.models.lm import DecoderLM
     t = time.monotonic()
-    params = jax.jit(DecoderLM(cfg).init)(jax.random.PRNGKey(seed))
-    eng = run.build_engine(cell, cfg, params, compile_cache=compile_cache)
+    model, params = run.build_model(cfg, seed)
+    eng = run.build_engine(cell, model, params, compile_cache=compile_cache)
     run.warm_up(eng, cell, seed)
     reqs, _, _, _ = run.serve_window(eng, cell, seed, seconds)
-    del eng, params
+    del eng, model, params
     gc.collect()
     sample = run.check_sample(reqs, cell, seed)
     served, ctrl = run.reference_gaps(cell, seed, sample, control)
@@ -82,11 +80,12 @@ def main(argv=None) -> int:
     except run.NoChip as e:
         print(f"error: {e}", file=sys.stderr)
         return 2
+    from repro.models import registry
     from repro.runtime.fastpath import CompiledForwardCache
     run.use_compile_cache()
+    cfg = run.program_config(cell)
     if args.fault:
-        faults.plant(args.fault)
-    cfg = run.program_config(cell.config)
+        faults.plant(args.fault, type(registry.build_model(cfg)))
     cc = CompiledForwardCache()
     rows = []
     for i, seed in enumerate(args.seeds):

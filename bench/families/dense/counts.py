@@ -11,7 +11,7 @@ operations.  Bias adds, norms, rotary embedding and softmax are left out
 
 from __future__ import annotations
 
-from arch import Arch
+from .arch import Arch
 
 
 def layer_matmul_params(a: Arch) -> int:

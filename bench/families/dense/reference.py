@@ -28,7 +28,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from arch import Arch
+from .arch import Arch
 
 _QUERY_BLOCK = 512
 

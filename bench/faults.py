@@ -44,13 +44,15 @@ def _stateless_step(step):
     return broken
 
 
-def plant(fault: str):
-    """Break the program with ``fault``; returns the undo."""
-    from repro.models.lm import DecoderLM
+def plant(fault: str, model_class):
+    """Break the program with ``fault``; returns the undo.
+    ``state_unchanged`` breaks the ``decode_step_q`` of ``model_class``,
+    the class of the model the harness builds for the cell
+    (``type(model)`` of ``run.build_model``)."""
     from repro.runtime import decode_engine
     if fault == "state_unchanged":
-        owner, name = DecoderLM, "decode_step_q"
-        new = _stateless_step(DecoderLM.decode_step_q)
+        owner, name = model_class, "decode_step_q"
+        new = _stateless_step(model_class.decode_step_q)
     elif fault in FAULTS:
         owner, name = decode_engine, "_build_fused_decode"
         new = _broken_chunk(decode_engine._build_fused_decode, fault)

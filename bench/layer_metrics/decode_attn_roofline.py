@@ -3,13 +3,12 @@ least time is the larger of the bytes over HBM bandwidth and the
 operations over the bf16 peak, for the work the algorithm needs: the
 k/v codes and scales at each decoded token's live cache length, q read
 and the output written, 4·len·dh operations per head
-(``counts.decode_attn_work``).  At int8 codes that is about two
+(``run.counts.decode_attn_work``).  At int8 codes that is about two
 operations a byte, so HBM bandwidth bounds it.  Kernel time is the
 device time of the Pallas kernel ops (``tpu_custom_call``; the decode
 chunk runs no other) inside ``decode.chunk`` spans.
 Moves ``tpot_p95_ms``."""
 
-import counts
 import trace_reduce
 
 def read(run):
@@ -22,8 +21,8 @@ def read(run):
         return None
     flops = nbytes = 0
     for _, n in trace_reduce.decoded_tokens(run):
-        f, b = counts.decode_attn_work(run.arch, n, run.code_bytes)
+        f, b = run.counts.decode_attn_work(run.arch, n, run.code_bytes)
         flops += f
         nbytes += b
-    least, _ = counts.least_time(flops, nbytes, run.peaks)
+    least, _ = run.counts.least_time(flops, nbytes, run.peaks)
     return 100.0 * least / kernel_s

@@ -2,7 +2,6 @@
 length, dead slots excluded) over the device time of the fused decode
 executables, against the chip's bf16 peak.  Moves ``tpot_p95_ms``."""
 
-import counts
 import trace_reduce
 
 
@@ -13,6 +12,6 @@ def read(run):
     dev_s = trace_reduce.device_seconds_within(run.trace, chunks)
     if not dev_s:
         return None
-    flops = sum(counts.decode_token_flops(run.arch, n)
+    flops = sum(run.counts.decode_token_flops(run.arch, n)
                 for _, n in trace_reduce.decoded_tokens(run))
     return 100.0 * flops / dev_s / run.peaks["flops_bf16"]

@@ -3,7 +3,6 @@ the device time of the prefill executables, against the chip's bf16
 peak.  Ops are attributed to a prefill by starting inside its
 ``decode.prefill`` span.  Moves ``ttft_p95_ms``."""
 
-import counts
 import trace_reduce
 
 
@@ -17,5 +16,6 @@ def read(run):
                                                [(s, e) for s, e, _ in pre])
     if not dev_s:
         return None
-    flops = sum(counts.prefill_flops(run.arch, plen[rid]) for _, _, rid in pre)
+    flops = sum(run.counts.prefill_flops(run.arch, plen[rid])
+                for _, _, rid in pre)
     return 100.0 * flops / dev_s / run.peaks["flops_bf16"]

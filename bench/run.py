@@ -8,7 +8,35 @@ configuration (``bench/configs/<config>.json``) under a traffic mix
 (``bench/mixes/<traffic>.json``, read by ``bench/traffic/<generator>.py``),
 with its output limits in ``bench/limits/<workload>.json``.  Per-layer
 metrics are read by ``bench/layer_metrics/<metric>.py``.  Everything is
-found by name, so a new cell, mix or metric is a new file plus an entry.
+found by name, so a new configuration of any family is new files plus
+entries.
+
+Whatever depends on the model's family comes from the configuration
+file, which names it:
+
+- ``"family"``: the directory ``bench/families/<family>/``, loaded as a
+  package of its own (:func:`load_family`), whose modules may import
+  each other relatively.  The dense GQA family is ``dense``.  It holds:
+
+  - ``arch.py``: ``from_config(conf)``, the arch object the other two
+    take, which exposes at least ``vocab``, ``n_layers`` and
+    ``split_layer``; and ``program_fields(arch)``, the program
+    ``ModelConfig``'s fields that the file fixes, with their values.
+  - ``counts.py``: ``prefill_flops(arch, prompt_len)``,
+    ``decode_token_flops(arch, ctx_len)``, ``decode_attn_work(arch,
+    ctx_len, code_bytes) -> (flops, bytes)`` and ``least_time(flops,
+    nbytes, peaks) -> (seconds, bound)``; per-layer readers find them
+    as ``run.counts``.
+  - ``reference.py``: ``init_params(arch, key)``, ``agent_quantized(arch,
+    params, b_hat)`` and ``logits_rows(arch, params, tokens, n_prompt, *,
+    n_rows, b_kv, lowp=False)``, the float32 logits [n_rows, vocab] that
+    score the served tokens.  It imports nothing of the program.
+- ``"program_arch"`` and ``"program_overrides"``: the program's
+  ``ModelConfig``, ``repro.configs.<program_arch>.FULL`` with the
+  overrides, refused where it differs from ``program_fields``.  The
+  program's own registry (``repro.models.registry.build_model``) picks
+  the model class for it; ``bench/faults.py`` plants
+  ``state_unchanged`` on that class's ``decode_step_q``.
 
 The run: weights from the seed on the device, the engine built and its
 executables warmed with the cell's own shapes (set-up, reported as
@@ -17,7 +45,7 @@ served through ``DecodeEngine.submit``/``step``, each token stamped with
 the host clock as it reaches the host.  Requests due in the window and
 still in flight at its close are drained after it.  Then the program's
 state is freed and a sample of the served tokens is scored against the
-plain reference (``bench/reference.py``): ``correct`` holds when every
+configuration's plain reference: ``correct`` holds when every
 number compared is within its limit.  ``--trace 1`` runs the window under
 the JAX profiler and reports the per-layer metrics instead of the
 end-to-end ones.
@@ -55,7 +83,6 @@ if str(BENCH) not in sys.path:
 import numpy as np  # noqa: E402
 
 import trace_reduce  # noqa: E402
-from arch import Arch  # noqa: E402
 from peaks import peaks_for  # noqa: E402
 
 
@@ -80,6 +107,28 @@ def load_module(path: pathlib.Path):
     return mod
 
 
+FAMILY_MODULES = ("arch", "counts", "reference")
+_FAMILIES: dict = {}
+
+
+def load_family(path: pathlib.Path) -> types.ModuleType:
+    """The model family in the directory ``path``: a package of its own,
+    under a name of its own, whose modules :data:`FAMILY_MODULES` are
+    executed once per process."""
+    path = pathlib.Path(path).resolve()
+    if path not in _FAMILIES:
+        name = f"bench_family_{len(_FAMILIES)}_" + path.name \
+            .replace(".", "_").replace("-", "_")
+        spec = importlib.util.spec_from_loader(name, None, is_package=True)
+        pkg = importlib.util.module_from_spec(spec)
+        pkg.__path__ = [str(path)]
+        sys.modules[name] = pkg
+        for part in FAMILY_MODULES:
+            importlib.import_module(f"{name}.{part}")
+        _FAMILIES[path] = pkg
+    return _FAMILIES[path]
+
+
 @dataclasses.dataclass
 class Cell:
     name: str
@@ -90,10 +139,11 @@ class Cell:
     end_to_end: list
     per_layer: list
     bench: pathlib.Path
+    family: types.ModuleType     # the package the configuration names
 
     @property
-    def arch(self) -> Arch:
-        return Arch.from_config(self.config)
+    def arch(self):
+        return self.family.arch.from_config(self.config)
 
 
 def load_cell(name: str, root: pathlib.Path = ROOT) -> Cell:
@@ -110,11 +160,12 @@ def load_cell(name: str, root: pathlib.Path = ROOT) -> Cell:
     per_layer = [m for m in spec["per_layer"]
                  if name in m.get("workloads", [name] if m["moves"] in moved
                                   else [])]
-    return Cell(name=name, chips=int(w["chips"]),
-                config=_load_json(root / conf["file"]),
+    config = _load_json(root / conf["file"])
+    return Cell(name=name, chips=int(w["chips"]), config=config,
                 mix=_load_json(bench / "mixes" / f"{w['traffic']}.json"),
                 limits=_load_json(bench / "limits" / f"{name}.json"),
-                end_to_end=e2e, per_layer=per_layer, bench=bench)
+                end_to_end=e2e, per_layer=per_layer, bench=bench,
+                family=load_family(bench / "families" / config["family"]))
 
 
 def find_chips(chips: int):
@@ -141,22 +192,16 @@ def find_chips(chips: int):
 _BITS = {"float64": 64, "float32": 32, "bfloat16": 16, "float16": 16}
 
 
-def program_config(conf: dict):
-    """The program's ModelConfig for a configuration file, checked
-    against the file's published numbers: the file says what runs.  The
-    program may hold and compute at the precision the file states
-    (``torch_dtype``) or wider, never narrower."""
-    import importlib
+def program_config(cell: Cell):
+    """The program's ModelConfig for the cell's configuration file,
+    checked against the fields its family's arch module says the file
+    fixes (``program_fields``): the file says what runs.  The program may hold
+    and compute at the precision the file states (``torch_dtype``) or
+    wider, never narrower."""
+    conf = cell.config
     base = importlib.import_module(f"repro.configs.{conf['program_arch']}")
     cfg = dataclasses.replace(base.FULL, **conf.get("program_overrides", {}))
-    a = Arch.from_config(conf)
-    want = {"d_model": a.d_model, "n_layers": a.n_layers,
-            "n_heads": a.n_heads, "n_kv_heads": a.n_kv_heads,
-            "head_dim": a.head_dim, "d_ff": a.d_ff, "vocab_size": a.vocab,
-            "tie_embeddings": a.tied, "norm": a.norm,
-            "qkv_bias": a.qkv_bias, "rope_theta": a.rope_theta,
-            "split_layer": a.split_layer, "act": "silu", "n_experts": 0,
-            "sliding_window": 0}
+    want = cell.family.arch.program_fields(cell.arch)
     bad = {k: (getattr(cfg, k), v) for k, v in want.items()
            if getattr(cfg, k) != v}
     stated = conf["model"]["torch_dtype"]
@@ -169,18 +214,28 @@ def program_config(conf: dict):
     return cfg
 
 
-def build_engine(cell: Cell, cfg, params, tracer=None, compile_cache=None):
+def build_model(cfg, seed: int):
+    """(model, weights): the model the program's registry builds for
+    ``cfg``, and its weights from ``seed`` in one jitted call on the
+    device."""
+    import jax
+    from repro.models import registry
+    model = registry.build_model(cfg)
+    return model, jax.jit(model.init)(jax.random.PRNGKey(seed))
+
+
+def build_engine(cell: Cell, model, params, tracer=None,
+                 compile_cache=None):
     """The DecodeEngine as ``launch/serve.py --decode`` builds it
     (continuous admission), with one QoS class pinned at the mix's
     operating point.  The system parameters and λ statistics feed only
     the codesign and the modeled clock, which a pinned point and a
     wall-clock benchmark do not use, so λ is given rather than fitted."""
     from repro.core.cost_model import SystemParams
-    from repro.models.lm import DecoderLM
     from repro.runtime.decode_engine import DecodeEngine
     from repro.runtime.serve_engine import QosClass
     mix = cell.mix
-    eng = DecodeEngine(DecoderLM(cfg), params,
+    eng = DecodeEngine(model, params,
                        SystemParams(n_flop_agent=1.0, n_flop_server=1.0),
                        classes=[QosClass(mix["qos"], t0=1.0, e0=1.0)],
                        max_batch=mix["max_batch"],
@@ -243,10 +298,11 @@ def serve_window(eng, cell: Cell, seed: int, seconds: float,
     clock annotation.
 
     With ``profile_dir``, the window is cut short: the profiler starts
-    after the mix's ``trace_lead_s`` (the traffic settles first) and the
-    window closes when it has recorded ``trace_s``.  A device trace
-    holds every operation, hundreds of thousands a second, and reading
-    it has to fit a run's time."""
+    after the mix's ``trace_lead_s`` (the traffic settles first), or
+    after the engine call running then, and the window closes when it
+    has recorded ``trace_s``.  A device trace holds every operation,
+    hundreds of thousands a second, and reading it has to fit a run's
+    time."""
     import jax
     mix = cell.mix
     gen = load_module(cell.bench / "traffic" / f"{mix['generator']}.py")
@@ -274,15 +330,16 @@ def serve_window(eng, cell: Cell, seed: int, seconds: float,
     t_end = t0 + (lead + trace_s if profile_dir is not None else seconds)
     while True:
         now = time.monotonic()
-        if now >= t_end:
-            break
         if profile_dir is not None and traced is None and now >= t0 + lead:
             jax.profiler.start_trace(profile_dir)
             mark = time.monotonic()
             with jax.profiler.TraceAnnotation(trace_reduce.CLOCK_MARK):
                 pass
             traced = (mark, time.monotonic())
+            t_end = traced[1] + trace_s
             continue
+        if now >= t_end:
+            break
         submit_due(now)
         if eng.pending or eng.in_flight:
             eng.step()
@@ -306,18 +363,25 @@ def p95(values):
         if len(values) else None
 
 
-def end_to_end(reqs, t0: float, seconds: float) -> dict:
-    """The user-visible numbers of a window (before ``setup_s``)."""
+def end_to_end(reqs, t0: float, t_close: float) -> dict:
+    """The user-visible numbers of a window (before ``setup_s``).
+
+    ``tokens_per_s`` is every token that reached the host from the
+    window's open to its close, over that time.  The window closes when
+    the engine call running at its planned end returns, so that call's
+    tokens count with its time: a chunk emits its tokens together, and
+    cutting the count at the planned end would add or drop a whole
+    chunk's tokens against the same time."""
     fin = [r for r in reqs if r.done]
     ttft = [r.times[0] - r.due for r in fin]
     lat = [r.times[-1] - r.due for r in fin]
     tpot = [(r.times[-1] - r.times[0]) / (len(r.times) - 1)
             for r in fin if len(r.times) > 1]
-    emitted = sum(1 for r in reqs for t in r.times if t0 <= t < t0 + seconds)
+    emitted = sum(1 for r in reqs for t in r.times if t0 <= t <= t_close)
     return {"ttft_p95_ms": ("ms", 1e3 * p95(ttft) if ttft else None),
             "latency_p95_ms": ("ms", 1e3 * p95(lat) if lat else None),
             "tpot_p95_ms": ("ms", 1e3 * p95(tpot) if tpot else None),
-            "tokens_per_s": ("tokens/s", emitted / seconds)}
+            "tokens_per_s": ("tokens/s", emitted / (t_close - t0))}
 
 
 # ---------------------------------------------------------------------------
@@ -351,8 +415,7 @@ def reference_gaps(cell: Cell, seed: int, sample, control=False):
     reference: the lower-precision control."""
     import jax
     import jax.numpy as jnp
-    import reference as ref
-    a, mix = cell.arch, cell.mix
+    ref, a, mix = cell.family.reference, cell.arch, cell.mix
     n_rows, s_len = mix["output_len"][1], mix["reference_len"]
     p = jax.jit(lambda k: ref.agent_quantized(
         a, ref.init_params(a, k), mix["b_hat"]))(jax.random.PRNGKey(seed))
@@ -434,8 +497,8 @@ def layer_context(cell: Cell, reqs, spans, instants, window, trace,
         spans=[s for s in spans if lo <= s[1] and s[2] <= hi],
         instants=[i for i in instants if lo <= i[1] <= hi],
         requests=reqs, window=window, trace=trace, arch=cell.arch,
-        max_batch=cell.mix["max_batch"], peaks=peaks,
-        code_bytes=1 if cell.mix["b_kv"] < 16 else 4)
+        counts=cell.family.counts, max_batch=cell.mix["max_batch"],
+        peaks=peaks, code_bytes=1 if cell.mix["b_kv"] < 16 else 4)
 
 
 def read_layer_metrics(cell: Cell, ctx) -> dict:
@@ -524,11 +587,9 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
             compiles.append(time.monotonic())
     jax.monitoring.register_event_duration_secs_listener(on_event)
 
-    cfg = program_config(cell.config)
-    from repro.models.lm import DecoderLM
-    params = jax.jit(DecoderLM(cfg).init)(jax.random.PRNGKey(seed))
+    model, params = build_model(program_config(cell), seed)
     tracer = Tracer() if trace else None
-    eng = build_engine(cell, cfg, params, tracer=tracer)
+    eng = build_engine(cell, model, params, tracer=tracer)
     warm_up(eng, cell, seed)
     n_compiled = eng.report().compile_misses
     profile_dir = tempfile.mkdtemp(prefix="bench-trace-") if trace else None
@@ -553,7 +614,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
     finally:
         if profile_dir is not None:
             shutil.rmtree(profile_dir, ignore_errors=True)
-    del eng, params
+    del eng, model, params
     gc.collect()
 
     lateness = [r.submitted - r.due for r in reqs]
@@ -583,7 +644,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
         result["device"] = device
         result["breakdown"] = breakdown(ctx)
     else:
-        e2e = end_to_end(reqs, t0, seconds)
+        e2e = end_to_end(reqs, t0, t_close)
         e2e["setup_s"] = ("s", setup_s)
         wanted = {m["name"] for m in cell.end_to_end}
         result["metrics"] = {k: {"value": v, "unit": u}

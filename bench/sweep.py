@@ -38,12 +38,9 @@ def main(argv=None) -> int:
     except run.NoChip as e:
         print(f"error: {e}", file=sys.stderr)
         return 2
-    import jax
-    from repro.models.lm import DecoderLM
     run.use_compile_cache()
-    cfg = run.program_config(cell.config)
-    params = jax.jit(DecoderLM(cfg).init)(jax.random.PRNGKey(args.seed))
-    eng = run.build_engine(cell, cfg, params)
+    model, params = run.build_model(run.program_config(cell), args.seed)
+    eng = run.build_engine(cell, model, params)
     run.warm_up(eng, cell, args.seed)
     for rate in args.rates:
         at = dataclasses.replace(cell, mix={**cell.mix, "rate_rps": rate})

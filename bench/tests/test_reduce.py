@@ -1,6 +1,7 @@
 """The yardstick's arithmetic on the CPU: operation and byte counts
 against hand-worked numbers, and every per-layer reader on a small span
-list and device trace whose answers are worked out by hand here."""
+list and device trace whose answers are worked out by hand here.  The
+arch and the counts are the ones the configuration files name."""
 
 import dataclasses
 import pathlib
@@ -12,12 +13,14 @@ import pytest
 BENCH = pathlib.Path(__file__).resolve().parents[1]
 sys.path.insert(0, str(BENCH))
 
-import counts  # noqa: E402
 import run  # noqa: E402
 import trace_reduce  # noqa: E402
-from arch import Arch  # noqa: E402
 from peaks import PEAKS, peaks_for  # noqa: E402
 
+QWEN_CELL = run.load_cell("qwen2-0.5b.embodied")
+STABLELM_CELL = run.load_cell("stablelm-3b-5l.longctx")
+Arch = QWEN_CELL.family.arch.Arch
+counts = QWEN_CELL.family.counts   # the dense family's, as configured
 QWEN = Arch(d_model=896, n_layers=24, n_heads=14, n_kv_heads=2, head_dim=64,
             d_ff=4864, vocab=151936, tied=True, norm="rmsnorm",
             norm_eps=1e-6, qkv_bias=True, rope_theta=1e6,
@@ -30,8 +33,8 @@ V5E = PEAKS["TPU v5 lite"]
 
 
 def test_configs_on_disk_are_these_archs():
-    assert run.load_cell("qwen2-0.5b.embodied").arch == QWEN
-    assert run.load_cell("stablelm-3b-5l.longctx").arch == STABLELM
+    assert QWEN_CELL.arch == QWEN
+    assert STABLELM_CELL.arch == STABLELM
 
 
 def test_qwen2_counts_by_hand():
@@ -52,6 +55,7 @@ def test_qwen2_counts_by_hand():
 
 
 def test_stablelm_counts_by_hand():
+    counts = STABLELM_CELL.family.counts
     # 2560·(2560 + 2·2560) + 2560·2560 + 3·2560·6912 = 79,298,560
     assert counts.layer_matmul_params(STABLELM) == 79_298_560
     # 2·79,298,560·5 + 4·80·32·5·3500 + 2·2560·50,304
@@ -101,7 +105,7 @@ def hand_run():
     return types.SimpleNamespace(
         spans=spans, instants=instants, requests=reqs, window=(0.0, 1.0),
         trace={"window": [0.0, 1.0], "devices": {"/device:TPU:0": ops}},
-        arch=QWEN, max_batch=16, peaks=V5E, code_bytes=1)
+        arch=QWEN, counts=counts, max_batch=16, peaks=V5E, code_bytes=1)
 
 
 def read(name, ctx):
@@ -186,14 +190,30 @@ def test_readers_on_a_recorded_chip_trace(tmp_path):
             for r in side["requests"]]
     ctx = types.SimpleNamespace(
         spans=spans, instants=[tuple(i) for i in side["instants"]],
-        requests=reqs, window=window, trace=trace, arch=QWEN, max_batch=16,
+        requests=reqs, window=window, trace=trace, arch=QWEN,
+        counts=QWEN_CELL.family.counts, max_batch=16,
         peaks=peaks_for(side["device_kind"]), code_bytes=1)
     for name in ("prefill_mfu", "decode_mfu", "decode_attn_roofline",
                  "device_idle_share"):
         v = read(name, ctx)
         assert v is not None and 0 < v < 100, name
     assert read("prefill_ms", ctx) > 0 and read("decode_step_ms", ctx) > 0
+    # what the readers read on this slice before the configurations named
+    # their own arch reader and counts; a change to the dense family's
+    # counts or to a reader shows here
+    pinned = {"prefill_mfu": 15.525146020751972,
+              "decode_mfu": 0.3027457462358448,
+              "decode_attn_roofline": 1.0240353419377217,
+              "device_idle_share": 15.749099995690209,
+              "prefill_ms": 9.726000000005305,
+              "decode_step_ms": 9.082500000000474,
+              "batch_occupancy": 31.25}
+    for name, value in pinned.items():
+        assert read(name, ctx) == pytest.approx(value, rel=1e-12), name
     b = run.breakdown(ctx)
     assert b["device_ops"][0][0].startswith(("prefill:", "decode:"))
+    assert b["device_ops"][0] == ["decode:%closed_call.12",
+                                  pytest.approx(0.007561628999999992,
+                                                rel=1e-12)]
     idle = sum(v for _, v in b["idle_gaps"])
     assert idle == pytest.approx(window[1] - window[0] - busy)

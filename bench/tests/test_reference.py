@@ -21,12 +21,14 @@ import pytest
 BENCH = pathlib.Path(__file__).resolve().parents[1]
 sys.path.insert(0, str(BENCH))
 
-import reference as ref  # noqa: E402
-from arch import Arch  # noqa: E402
+import run  # noqa: E402
 
 from repro.configs import qwen2_0_5b, stablelm_3b  # noqa: E402
 from repro.models.lm import DecoderLM  # noqa: E402
 
+DENSE = BENCH / "families" / "dense"
+ref = run.load_family(DENSE).reference
+Arch = run.load_family(DENSE).arch.Arch
 TOL = 2e-5
 
 
@@ -103,7 +105,7 @@ def test_reference_rounds_the_agent_and_the_cache():
 
 def test_reference_imports_nothing_of_the_program():
     for f in ("reference.py", "arch.py"):
-        tree = ast.parse((BENCH / f).read_text())
+        tree = ast.parse((DENSE / f).read_text())
         names = [n.module or "" for n in ast.walk(tree)
                  if isinstance(n, ast.ImportFrom)]
         names += [a.name for n in ast.walk(tree) if isinstance(n, ast.Import)

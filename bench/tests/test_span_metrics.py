@@ -20,7 +20,7 @@ import run  # noqa: E402
 import trace_reduce  # noqa: E402
 from peaks import PEAKS, peaks_for  # noqa: E402
 from test_harness import run_tiny, tiny_root  # noqa: E402,F401
-from test_reduce import QWEN, R  # noqa: E402
+from test_reduce import QWEN, QWEN_CELL, R  # noqa: E402
 
 
 def read(name, ctx):
@@ -59,7 +59,8 @@ def hand_run(devices):
         spans=spans, instants=instants, requests=[], window=(0.0, 1.0),
         trace={"window": [0.0, 1.0],
                "devices": {k: ops[k] for k in devices}},
-        arch=QWEN, max_batch=16, peaks=PEAKS["TPU v5 lite"], code_bytes=1)
+        arch=QWEN, counts=QWEN_CELL.family.counts, max_batch=16,
+        peaks=PEAKS["TPU v5 lite"], code_bytes=1)
 
 
 def test_step_host_ms_by_hand():
@@ -115,8 +116,8 @@ def test_recorded_chip_trace_without_step_spans(tmp_path):
         window=window,
         trace=trace_reduce.reduce_xplane(str(tmp_path), side["mark"],
                                          window),
-        arch=QWEN, max_batch=16, peaks=peaks_for(side["device_kind"]),
-        code_bytes=1)
+        arch=QWEN, counts=QWEN_CELL.family.counts, max_batch=16,
+        peaks=peaks_for(side["device_kind"]), code_bytes=1)
     assert read("step_host_ms", ctx) is None
     assert read("idle_with_work_share", ctx) is None
     before = {"queue_wait_p95_ms": None, "batch_occupancy": 31.25,

@@ -7,9 +7,14 @@ Mix parameters: ``streams``, ``prompt_len`` [lo, hi], ``output_len``
 window opens.  (Staggered starts would not spread the prefills: the
 engine runs a chunk of up to 64 decode steps for the first request
 before it sees the next.)  Requests are taken in order from one pool whose
-lengths are evenly spaced draws from the uniform ranges; the seed
-permutes the pool and draws the token ids, so every seed has the same
-work in another order.
+lengths are evenly spaced draws from the uniform ranges.
+
+Every seed gets the same work: the pool's order is drawn once, from a
+fixed seed, and the seed draws only the token ids.  In a closed loop the
+order of the lengths is the schedule: it decides which replies end on the
+same decode step, and so how many prompts queue for prefill together.  A
+pool permuted by the seed gave each seed a schedule of its own and moved
+the first-token tail by whole prefills from seed to seed.
 """
 
 from __future__ import annotations
@@ -19,6 +24,8 @@ import heapq
 import numpy as np
 
 _POOL = 4096
+# the pool's one order, the same for every --seed
+_ORDER_SEED = 0
 
 
 def spread(lo: int, hi: int, n: int) -> np.ndarray:
@@ -29,9 +36,9 @@ def spread(lo: int, hi: int, n: int) -> np.ndarray:
 
 class Source:
     def __init__(self, mix: dict, seed: int, seconds: float, vocab: int):
-        rng = np.random.default_rng(seed)
-        self._plen = rng.permutation(spread(*mix["prompt_len"], _POOL))
-        self._olen = rng.permutation(spread(*mix["output_len"], _POOL))
+        order = np.random.default_rng(_ORDER_SEED)
+        self._plen = order.permutation(spread(*mix["prompt_len"], _POOL))
+        self._olen = order.permutation(spread(*mix["output_len"], _POOL))
         self._think = float(mix["think_s"])
         self._ready = [0.0] * int(mix["streams"])
         self._seed = seed

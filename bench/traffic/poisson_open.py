@@ -2,17 +2,23 @@
 whether or not earlier ones have finished (independent users).
 
 Mix parameters: ``rate_rps``, ``prompt_len`` [lo, hi], ``output_len``
-[lo, hi].  Every seed gets the same work in another order: the
-inter-arrival gaps are the n quantiles of an exponential distribution
-(n = rate × window), scaled to fill the window exactly, and the lengths
-are n evenly spaced draws from their uniform ranges; the seed permutes
-both and draws the token ids.  So two seeds differ in order and content,
-never in how much there is to do.
+[lo, hi].  The inter-arrival gaps are the n quantiles of an exponential
+distribution (n = rate × window), scaled to fill the window exactly, and
+the lengths are n evenly spaced draws from their uniform ranges.
+
+Every seed gets the same work: the order of gaps and lengths is drawn
+once, from a fixed seed, and the seed draws only the token ids.  The
+order of the gaps is the schedule: it decides where arrivals bunch, and
+so the queue behind them.  Gaps permuted by the seed gave each seed
+bursts of its own, and tails that moved with the seed.
 """
 
 from __future__ import annotations
 
 import numpy as np
+
+# the one order of gaps and lengths, the same for every --seed
+_ORDER_SEED = 0
 
 
 def spread(lo: int, hi: int, n: int) -> np.ndarray:
@@ -23,13 +29,13 @@ def spread(lo: int, hi: int, n: int) -> np.ndarray:
 
 class Source:
     def __init__(self, mix: dict, seed: int, seconds: float, vocab: int):
-        rng = np.random.default_rng(seed)
+        order = np.random.default_rng(_ORDER_SEED)
         n = max(1, int(round(float(mix["rate_rps"]) * seconds)))
-        gaps = rng.permutation(-np.log1p(-(np.arange(n) + 0.5) / n))
+        gaps = order.permutation(-np.log1p(-(np.arange(n) + 0.5) / n))
         gaps *= seconds / gaps.sum()
         self._due = np.concatenate([[0.0], np.cumsum(gaps)[:-1]])
-        self._plen = rng.permutation(spread(*mix["prompt_len"], n))
-        self._olen = rng.permutation(spread(*mix["output_len"], n))
+        self._plen = order.permutation(spread(*mix["prompt_len"], n))
+        self._olen = order.permutation(spread(*mix["output_len"], n))
         self._seed = seed
         self._vocab = vocab
         self._next = 0
